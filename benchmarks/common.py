@@ -2,7 +2,13 @@
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Tuple
+import json
+import os
+import subprocess
+import sys
+import tempfile
+from pathlib import Path
+from typing import Callable, Dict, Sequence, Tuple
 
 from repro.core import freezing
 from repro.core.decompose import Decomposer, apply_lrd
@@ -58,3 +64,43 @@ def record(name: str, rows, out_dir: str = "benchmarks/results") -> str:
             log.emit("bench_row", bench=name, row=row)
         log.emit("run_end", kind="bench", bench=name, rows=len(rows))
     return str(path)
+
+
+def rows_on_devices(needed: int, run_rows: Callable[[], list], module: str,
+                    child_args: Sequence[str] = ()) -> list:
+    """Rows of a benchmark section that needs ``needed`` devices.
+
+    With that many devices, ``run_rows()`` runs in this process.  Otherwise,
+    and only when this process's JAX is on the CPU, the section re-executes
+    ``python -m <module> <child_args> --json-out FILE`` under a forced
+    ``needed``-device host platform and reads the rows back: jax pins the
+    device count at first init, and a process on the CPU holds no
+    accelerator that its child could need.  A process on an accelerator
+    with too few devices raises instead — one chip belongs to one process,
+    so a child would fail to reach it, hang, or measure CPU devices.
+    """
+    import jax
+
+    devices = jax.devices()
+    if len(devices) >= needed:
+        return run_rows()
+    platform = devices[0].platform
+    if platform != "cpu":
+        raise RuntimeError(f"{module}: needs {needed} devices, found "
+                           f"{len(devices)} {platform} device(s)")
+    root = Path(__file__).resolve().parent.parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                        f"platform_device_count={needed}").strip()
+    env["PYTHONPATH"] = str(root / "src") + os.pathsep + env.get(
+        "PYTHONPATH", "")
+    with tempfile.TemporaryDirectory() as td:
+        out = Path(td) / "rows.json"
+        proc = subprocess.run(
+            [sys.executable, "-m", module, *child_args, "--json-out",
+             str(out)], cwd=root, env=env, capture_output=True, text=True,
+            timeout=1800)
+        if proc.returncode != 0:
+            raise RuntimeError(f"{module} child failed:\n{proc.stdout}\n"
+                               f"{proc.stderr[-3000:]}")
+        return json.loads(out.read_text())

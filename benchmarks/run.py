@@ -32,6 +32,9 @@ def main() -> None:
                     help="write rows to benchmarks/results/BENCH_*.json")
     args, _ = ap.parse_known_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     failures = []
 
     def guard(title, fn, record_as=None):

@@ -45,10 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
-import subprocess
 import sys
-import tempfile
 from pathlib import Path
 
 import jax
@@ -374,16 +371,16 @@ def _prefix_rows(*, slots, requests, rate, block_size, seed, iters=3):
     return rows
 
 
-# -- TP-sharded rows (forced-8-device subprocess) ---------------------------
+# -- TP-sharded rows ---------------------------------------------------------
 
 TP_MESHES = (1, 2)
 TP_DRIFT_TOL = 1e-5
 
 
-def _sharded_child(json_out: str):
-    """Runs inside the forced-8-device subprocess: serve the same
-    shared-prefix trace through a 1-device and a model=2 TP mesh, gate on
-    compile-once, exact token parity, and decode logits drift."""
+def _sharded_run():
+    """Serve the same shared-prefix trace through a 1-device and a model=2
+    TP mesh, gate on compile-once, exact token parity, and decode logits
+    drift.  Needs two devices (``_sharded_rows``)."""
     import jax.numpy as jnp
 
     cfg = get_smoke_config("smollm-360m")
@@ -443,29 +440,16 @@ def _sharded_child(json_out: str):
     for row in rows:
         row["tp_logits_drift_max_abs"] = drift
         row["tp_logits_drift_tol"] = TP_DRIFT_TOL
-    Path(json_out).write_text(json.dumps(rows))
+    return rows
 
 
 def _sharded_rows():
-    """Re-exec under a forced-8-device host platform (jax pins the device
-    count at first init, so the parent can't widen it retroactively) and
-    read the TP rows back."""
-    root = Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    env["PYTHONPATH"] = str(root / "src") + os.pathsep + env.get(
-        "PYTHONPATH", "")
-    with tempfile.TemporaryDirectory() as td:
-        out = Path(td) / "rows.json"
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.serve_throughput",
-             "--sharded-child", "--json-out", str(out)],
-            cwd=root, env=env, capture_output=True, text=True, timeout=1800)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"sharded serving child failed:\n{proc.stdout}\n{proc.stderr}")
-        return json.loads(out.read_text())
+    """The TP rows: in-process with two devices, else (on a CPU) from a
+    forced 2-device host-platform child."""
+    from benchmarks.common import rows_on_devices
+
+    return rows_on_devices(max(TP_MESHES), _sharded_run,
+                           "benchmarks.serve_throughput", ("--sharded-child",))
 
 
 def run(slots=2, requests=8, rate=200.0, prompt_len=16, max_new=8,
@@ -538,8 +522,8 @@ def main(**kw):
     print(f"prefix cache saved {saved} prefill tokens "
           f"({by['lrd-prefix-off']['prefill_tokens']} -> "
           f"{by['lrd-prefix-on']['prefill_tokens']}) at exact parity")
-    print("# TP-sharded serving (forced-8-device subprocess): variant, "
-          "devices, trace tok/s, compile counts, logits drift")
+    print("# TP-sharded serving: variant, devices, trace tok/s, compile "
+          "counts, logits drift")
     for dm in TP_MESHES:
         r = by[f"tp-model{dm}"]
         print(f"{r['variant']},{r['devices']},{r['tok_per_s']:.1f}"
@@ -553,6 +537,7 @@ def main(**kw):
 
 if __name__ == "__main__":
     if "--sharded-child" in sys.argv:
-        _sharded_child(sys.argv[sys.argv.index("--json-out") + 1])
+        Path(sys.argv[sys.argv.index("--json-out") + 1]).write_text(
+            json.dumps(_sharded_run()))
     else:
         main()

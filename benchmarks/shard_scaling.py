@@ -13,12 +13,11 @@ phase the factor group's gradient all-reduce AND storage all-gather bytes
 are absent, so collective bytes at phase 0/1 sit strictly below the
 no-freeze row of the same mesh.
 
-Needs >= 8 devices; when launched on fewer (the usual CPU case) it
-re-execs itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — jax pins the
-device count at first init, so the parent process cannot force it
-retroactively.  Param layout is TP/no-FSDP + ZeRO rank-dim storage
-sharding, the layout whose collective schedule is tabulated in §9.
+Needs >= 8 devices and runs in-process when it has them; on a CPU with
+fewer (the usual case) it re-execs itself under a forced 8-device host
+platform (``benchmarks.common.rows_on_devices``).  Param layout is
+TP/no-FSDP + ZeRO rank-dim storage sharding, the layout whose collective
+schedule is tabulated in §9.
 
   PYTHONPATH=src python -m benchmarks.shard_scaling [--record] [--iters N]
 """
@@ -26,13 +25,7 @@ sharding, the layout whose collective schedule is tabulated in §9.
 from __future__ import annotations
 
 import argparse
-import functools
 import json
-import os
-import subprocess
-import sys
-import tempfile
-import time
 from pathlib import Path
 
 ARCH = "smollm-360m"
@@ -87,18 +80,13 @@ def _run(iters: int):
         for phase in phases:
             state, _ = steps.make_sharded_train_state(run, params, phase,
                                                       mesh)
-            shs = steps.state_shardings(run, mesh, state)
             batch = steps.shard_batch(batch_h, mesh)
-            fn = jax.jit(functools.partial(train, phase=phase),
-                         donate_argnums=(0,),
-                         in_shardings=(shs, steps.batch_shardings(batch,
-                                                                  mesh)),
-                         out_shardings=(shs, None))
-            compiled = fn.lower(state, batch).compile()
+            compiled = steps.compile_train_step(run, mesh, train, state,
+                                                batch, phase)
             coll = {k: int(v) for k, v in
                     analyze_hlo(compiled.as_text()).collective_bytes.items()}
 
-            # time the AOT executable directly — fn(...) would recompile
+            # time the AOT executable directly — a jit call would recompile
             # (the jit call cache is separate from lower().compile()) and
             # donation threads the state through the loop
             carry = {"state": state}
@@ -130,32 +118,11 @@ def _print(rows):
 
 
 def main(iters: int = 3, record: bool = False):
-    import jax
+    from benchmarks.common import rows_on_devices
 
-    if len(jax.devices()) >= NEEDED_DEVICES:
-        rows = _run(iters)
-    else:
-        # jax is already initialized with too few devices in this process:
-        # re-exec under a forced host platform and read the rows back.
-        root = Path(__file__).resolve().parent.parent
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={NEEDED_DEVICES}"
-        ).strip()
-        env["PYTHONPATH"] = str(root / "src") + os.pathsep + env.get(
-            "PYTHONPATH", "")
-        with tempfile.TemporaryDirectory() as td:
-            out = Path(td) / "rows.json"
-            proc = subprocess.run(
-                [sys.executable, "-m", "benchmarks.shard_scaling", "--child",
-                 "--iters", str(iters), "--json-out", str(out)],
-                cwd=root, env=env, capture_output=True, text=True,
-                timeout=1800)
-            if proc.returncode != 0:
-                raise RuntimeError(
-                    f"shard_scaling child failed:\n{proc.stderr[-3000:]}")
-            rows = json.loads(out.read_text())
+    rows = rows_on_devices(NEEDED_DEVICES, lambda: _run(iters),
+                           "benchmarks.shard_scaling",
+                           ("--child", "--iters", str(iters)))
     _print(rows)
     if record:
         from benchmarks.common import record as record_rows

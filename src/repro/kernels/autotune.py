@@ -11,8 +11,8 @@ best values depend on shape, dtype, and chip.  This module owns the search:
   and the survivors come back ordered by the analytic roofline time;
 * **measurement** times the analytically-best few candidates through the
   *real dispatcher* (``kernels.ops``) with a warm-up + median-of-k harness.
-  The dispatcher can silently take its jnp fallback (off-TPU, indivisible
-  local shards, manual-mesh regions); every fallback is captured via
+  The dispatcher can take its jnp fallback (off-TPU, tiles over the VMEM
+  budget, manual-mesh regions); every fallback is captured via
   ``ops.capture_fallbacks`` and a timing that did not exercise the kernel
   is NEVER recorded as ``source="measured"`` — it demotes to the analytic
   winner with the fallback reason attached;

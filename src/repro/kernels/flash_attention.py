@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 
 __all__ = ["flash_attention"]
 
@@ -97,6 +96,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                block_kv=bkv, scale=scale)
     return pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -110,7 +110,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),   # denominator l
             pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 
 __all__ = ["int8_matmul", "int8_lowrank_matmul", "quantize_rowwise",
            "quantize_colwise"]
@@ -97,6 +96,7 @@ def int8_matmul(x_q: jax.Array, w_q: jax.Array, *, block_m: int = 256,
         f"({block_m},{block_k},{block_n})")
     return pl.pallas_call(
         _dense_kernel,
+        name="int8_matmul",
         grid=(m // block_m, s // block_n, c // block_k),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
@@ -105,7 +105,7 @@ def int8_matmul(x_q: jax.Array, w_q: jax.Array, *, block_m: int = 256,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, s), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -159,6 +159,7 @@ def int8_lowrank_matmul(x_q: jax.Array, u_q: jax.Array, u_scale: jax.Array,
     kernel = functools.partial(_lowrank_kernel, out_dtype=jnp.float32)
     return pl.pallas_call(
         kernel,
+        name="int8_lowrank_matmul",
         grid=(m // block_m, s // block_n, c // block_k),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),  # x_q
@@ -170,7 +171,7 @@ def int8_lowrank_matmul(x_q: jax.Array, u_q: jax.Array, u_scale: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, s), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, r), jnp.int32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

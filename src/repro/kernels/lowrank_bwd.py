@@ -19,10 +19,11 @@ pathology the fused forward removes (DESIGN.md §3).  These kernels keep every
   (S/bn, M/bm, C/bk) with ``t`` rebuilt per (n, m) tile (S/bn recomputes).
 
 All three assume the same block divisibility as the forward kernel (the
-``ops.lowrank_apply`` dispatcher guarantees a VJP kernel only pairs with a
-kernel forward) and keep the full rank r per tile — rank quantization
-(Algorithm 1) makes r a multiple of the MXU tile, so the r-contractions
-waste no systolic-array lanes in the backward either.
+``ops.lowrank_apply`` dispatcher resolves one block choice per call and the
+VJP reuses it) and keep the full rank r per tile.  Where rank quantization
+(Algorithm 1) put r on a multiple of the MXU tile, the r-contractions waste
+no systolic-array lanes in the backward either; an r off the tile (see
+lowrank_matmul.py) pads them here as in the forward.
 
 Transposed operands are never materialized: the kernels read the same U/V/x
 blocks the forward reads and phrase the transpose as ``dot_general``
@@ -38,7 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 
 __all__ = ["lowrank_matmul_dx", "lowrank_matmul_du", "lowrank_matmul_dv"]
 
@@ -141,11 +141,12 @@ def lowrank_matmul_dx(
                                    block_n=block_n)
         return pl.pallas_call(
             kernel,
+            name="lowrank_dx_db",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, k)),  # dy
                 pl.BlockSpec((block_k, r), lambda i, j, k: (j, 0)),  # u
-                pl.BlockSpec(memory_space=pltpu.ANY),  # v: manual DMA
+                pl.BlockSpec(memory_space=pl.ANY),  # v: manual DMA
             ],
             out_specs=pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((m, c), dy.dtype),
@@ -154,7 +155,7 @@ def lowrank_matmul_dx(
                 pltpu.VMEM((2, r, block_n), v.dtype),  # two-slot V buffer
                 pltpu.SemaphoreType.DMA((2,)),
             ],
-            compiler_params=pallas_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -162,6 +163,7 @@ def lowrank_matmul_dx(
     kernel = functools.partial(_dx_kernel, out_dtype=dy.dtype)
     return pl.pallas_call(
         kernel,
+        name="lowrank_dx",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, k)),  # dy
@@ -171,7 +173,7 @@ def lowrank_matmul_dx(
         out_specs=pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, c), dy.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, r), jnp.float32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -241,6 +243,7 @@ def lowrank_matmul_du(
     kernel = functools.partial(_du_kernel, out_dtype=out_dtype)
     return pl.pallas_call(
         kernel,
+        name="lowrank_du",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda j, i, k: (i, j)),  # x
@@ -253,7 +256,7 @@ def lowrank_matmul_du(
             pltpu.VMEM((block_m, r), jnp.float32),  # dt tile
             pltpu.VMEM((block_k, r), jnp.float32),  # dU accumulator
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -325,6 +328,7 @@ def lowrank_matmul_dv(
     kernel = functools.partial(_dv_kernel, out_dtype=out_dtype)
     return pl.pallas_call(
         kernel,
+        name="lowrank_dv",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda j, i, k: (i, k)),  # x
@@ -337,7 +341,7 @@ def lowrank_matmul_dv(
             pltpu.VMEM((block_m, r), jnp.float32),  # t tile
             pltpu.VMEM((r, block_n), jnp.float32),  # dV accumulator
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 
 __all__ = ["lowrank_gated_ffn"]
 
@@ -64,6 +63,7 @@ def lowrank_gated_ffn(x, gu, gv, uu, uv, *, block_m: int = 256,
     kernel = functools.partial(_kernel, out_dtype=x.dtype)
     return pl.pallas_call(
         kernel,
+        name="lowrank_ffn_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),  # x
@@ -78,7 +78,7 @@ def lowrank_gated_ffn(x, gu, gv, uu, uv, *, block_m: int = 256,
             pltpu.VMEM((block_m, rg), jnp.float32),
             pltpu.VMEM((block_m, ru), jnp.float32),
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

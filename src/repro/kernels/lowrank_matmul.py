@@ -9,8 +9,10 @@ unless r is tiny.  TPU adaptation (DESIGN.md §2):
 * grid (M/bm, S/bn, C/bk); the (bm, r) intermediate lives in a VMEM scratch
   accumulator for the whole k-loop and never touches HBM;
 * rank r is the contracting dim of the second matmul — rank quantization
-  (Algorithm 1, analytic-tpu backend) guarantees it is a multiple of the MXU
-  tile (128), so the second matmul wastes no systolic-array lanes;
+  (Algorithm 1, analytic-tpu backend) puts it on a multiple of the MXU tile
+  (128) when its sweep window holds one, and then the second matmul wastes
+  no systolic-array lanes; where the window holds none (smollm-360m's q/o
+  and k/v: ranks 239 and 80) r stays off the tile and the lanes are padded;
 * block shapes default to (256, 512, 256): x-tile 256x512x2B = 256 KiB,
   U-tile 512 x r, V-tile r x 256 — for r <= 512 the whole working set is
   < 2 MiB, far under the ~16 MiB/core VMEM budget, leaving room for
@@ -30,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
 
 __all__ = ["lowrank_matmul"]
 
@@ -59,7 +60,7 @@ def _kernel_db(x_ref, u_hbm_ref, v_ref, o_ref, acc_ref, u_buf, u_sem,
                *, out_dtype, block_k):
     """Explicit two-slot DMA pipeline for the U stream.
 
-    U stays in ``pltpu.ANY`` (compiler-placed, HBM at these sizes) and is
+    U stays in ``pl.ANY`` (compiler-placed, HBM at these sizes) and is
     copied tile-by-tile into a double-buffered VMEM scratch: at k-step k the
     copy for tile k+1 is STARTED before the copy for tile k is awaited, so
     the (bk, r) U transfer for the next step overlaps the x@U MXU work of
@@ -118,7 +119,7 @@ def lowrank_matmul(
     """Fused ``(x @ u) @ v``.
 
     x: (M, C); u: (C, R); v: (R, S) -> (M, S).  M, C, S must be divisible by
-    the respective block sizes (``ops.lowrank_apply`` pads/falls back).  The
+    the respective block sizes (``ops.resolve_blocks`` picks ones that are).  The
     full rank R is kept per-tile (low-rank by construction: R <= 512 after
     quantization in every config we ship).  ``double_buffer`` switches the U
     stream to the explicit two-slot DMA pipeline (same numerics — asserted
@@ -138,10 +139,11 @@ def lowrank_matmul(
                                    block_k=block_k)
         return pl.pallas_call(
             kernel,
+            name="lowrank_fwd_db",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),  # x
-                pl.BlockSpec(memory_space=pltpu.ANY),  # u: manual DMA
+                pl.BlockSpec(memory_space=pl.ANY),  # u: manual DMA
                 pl.BlockSpec((r, block_n), lambda i, j, k: (0, j)),  # v
             ],
             out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
@@ -151,7 +153,7 @@ def lowrank_matmul(
                 pltpu.VMEM((2, block_k, r), u.dtype),  # two-slot U buffer
                 pltpu.SemaphoreType.DMA((2,)),  # one DMA sem per slot
             ],
-            compiler_params=pallas_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -159,6 +161,7 @@ def lowrank_matmul(
     kernel = functools.partial(_kernel, out_dtype=x.dtype)
     return pl.pallas_call(
         kernel,
+        name="lowrank_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),  # x
@@ -168,7 +171,7 @@ def lowrank_matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, s), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, r), jnp.float32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,11 +1,11 @@
-"""jit'd public wrappers around the Pallas kernels, with shape-legal
+"""jit'd public wrappers around the Pallas kernels, with counted
 fallbacks to the jnp reference path.
 
 ``lowrank_apply`` is the single entry point every model layer uses for a
 factorized linear — it routes to the fused Pallas kernel when (a) the
-platform can run it (TPU, or interpret mode for validation) and (b) the
-shapes are block-divisible; otherwise it runs the mathematically identical
-jnp path (which XLA still fuses reasonably on TPU, and which is the only
+caller asked for it and the platform can run it (TPU, or interpret mode
+for validation) and (b) :func:`resolve_blocks` finds legal blocks for the
+shape; otherwise it runs the mathematically identical jnp path (the only
 path exercised inside the 512-device SPMD dry-run — see DESIGN.md §3).
 ``lowrank_ffn_apply`` is the same dispatcher for the fused low-rank SwiGLU
 first half.
@@ -33,9 +33,9 @@ from typing import List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.distributed import sharding as _shmod
 from repro.kernels import ref
 from repro.kernels.int8_matmul import (int8_lowrank_matmul, int8_matmul,
@@ -51,7 +51,7 @@ __all__ = [
     "lowrank_apply", "lowrank_matmul_vjp",
     "lowrank_ffn_apply", "lowrank_ffn_vjp",
     "int8_apply", "int8_lowrank_apply",
-    "Fallback", "capture_fallbacks",
+    "Fallback", "capture_fallbacks", "resolve_blocks",
 ]
 
 _log = logging.getLogger(__name__)
@@ -61,12 +61,13 @@ _log = logging.getLogger(__name__)
 # Fallback accounting
 # --------------------------------------------------------------------------
 #
-# Every dispatcher below can silently take the jnp reference path (off-TPU,
-# indivisible shapes, shard_map regions with no legal mapping).  That is the
-# right behavior for model code — but a TIMING harness that thinks it
-# measured the kernel while it measured the fallback poisons the autotune
-# table.  ``capture_fallbacks`` records every fallback decision made while
-# the context is open (dispatch runs in Python at trace time, so notes fire
+# Every dispatcher below can take the jnp reference path (off-TPU, blocks
+# that fit no VMEM budget, shard_map regions with no legal mapping).  A
+# TIMING harness that thinks it measured the kernel while it measured the
+# fallback poisons the autotune table, and a chip run that thinks it ran
+# the paper's kernels while it ran jnp measures the wrong program.
+# ``capture_fallbacks`` records every fallback decision made while the
+# context is open (dispatch runs in Python at trace time, so notes fire
 # exactly when a call traces); kernels/autotune.py refuses to mint a
 # ``source="measured"`` entry whenever the capture is non-empty.
 #
@@ -74,9 +75,10 @@ _log = logging.getLogger(__name__)
 # ``kernel_fallbacks{op, reason}`` counter in the default metrics registry
 # (repro.obs — visible in production paths, not only tests) and (b) logs
 # once per unique (op, reason, shape): at WARNING for reasons that mean a
-# kernel the caller asked for silently degraded (indivisible blocks, mesh
-# mapping failures), at DEBUG for the expected ones ("platform" off-TPU,
-# "disabled" by policy) so CPU runs aren't spammed.
+# kernel the caller asked for degraded (VMEM overflow, mesh mapping
+# failures), at DEBUG for the expected ones ("platform" off-TPU,
+# "disabled" by policy) so CPU runs aren't spammed.  ``chip_smoke.py``
+# fails on any entry of the counter from its train phase.
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,7 +86,8 @@ class Fallback:
     """One dispatcher decision to run the jnp path instead of the kernel."""
 
     op: str
-    reason: str  # "platform" | "disabled" | "indivisible" | "mesh-*" | ...
+    reason: str  # "platform" | "disabled" | "vmem" | "mesh-*" | ...
+    #              ("indivisible": the int8 decode dispatchers only)
     shape: Tuple[int, ...] = ()
 
 
@@ -217,6 +220,41 @@ def kernel_available(platform: str | None = None) -> bool:
 
 def _divisible(m: int, c: int, s: int, bm: int, bk: int, bn: int) -> bool:
     return m % bm == 0 and c % bk == 0 and s % bn == 0
+
+
+# Mosaic's default scoped-VMEM limit is 16 MiB on the v5e this repo
+# targets; the rest is headroom for the compiler's own temporaries (dot
+# results, masks) that the estimate below does not count.
+VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _vmem_bytes(bm: int, bk: int, bn: int, r: int, itemsize: int) -> int:
+    """Peak VMEM of the fwd/dx/du/dv kernels at one block choice.
+
+    Each of the four streams the same four tiles — (bm, bk), (bm, bn),
+    (bk, r), (r, bn) — double-buffered by the grid pipeline, and holds f32
+    scratch of (bm, r) plus one of (bk, r) / (r, bn)."""
+    tiles = bm * bk + bm * bn + bk * r + r * bn
+    return 2 * itemsize * tiles + 4 * r * (bm + max(bk, bn))
+
+
+def resolve_blocks(m: int, c: int, s: int, r: int, dtype,
+                   blocks: Tuple[int, int, int]
+                   ) -> Optional[Tuple[int, int, int]]:
+    """Blocks the fused low-rank kernels can run ``(m, c) x (c, r) x (r, s)``
+    with, or None.
+
+    Each requested block is kept where it divides its dimension; otherwise
+    the whole dimension is the block, which the TPU tiling always accepts
+    (a block equal to the array dimension needs no (8, 128) alignment).
+    smollm-360m's 960-wide residual and 320-wide K/V take this path at the
+    default (256, 512, 256).  None when the resulting tiles overflow
+    :data:`VMEM_BUDGET`.  ``r`` is the rank the kernel holds per tile (the
+    sum of both branches' ranks for the gated FFN)."""
+    bm, bk, bn = (b if d % b == 0 else d for d, b in zip((m, c, s), blocks))
+    if _vmem_bytes(bm, bk, bn, r, jnp.dtype(dtype).itemsize) > VMEM_BUDGET:
+        return None
+    return bm, bk, bn
 
 
 def _tuned_blocks(op: str, m: int, c: int, r: int, s: int, dtype,
@@ -374,6 +412,44 @@ def _lr_sharded_bwd(mesh, batch_axes, model_axis, block_m, block_k, block_n,
 _lowrank_sharded.defvjp(_lr_sharded_fwd, _lr_sharded_bwd)
 
 
+def _kernel_plan(op: str, m: int, c: int, r: int, s: int, dtype,
+                 blocks: Tuple[int, int, int], *, use_kernel: Optional[bool],
+                 interpret: bool, autotune: bool,
+                 freeze_group: Optional[int], vmem_rank: Optional[int] = None):
+    """Where one fused low-rank call runs.
+
+    Returns ``(blocks, sctx)`` for the kernel path — ``sctx`` is the
+    ``(mesh, batch_axes, model_axis)`` shard_map mapping under a
+    multi-device mesh, else None — or None after noting the fallback.
+    Under a multi-device mesh the bare pallas_call would be replicated by
+    the partitioner (gathering every operand, frozen factors included), so
+    the choice there is shard_map or the jnp path, never the bare kernel;
+    blocks then resolve against the LOCAL shapes.
+    """
+    use = use_kernel if use_kernel is not None else (
+        kernel_available() or interpret)
+    if not use:
+        _note_fallback(op, "disabled" if use_kernel is not None
+                       else "platform", (m, c, s))
+        return None
+    if autotune:
+        blocks = _tuned_blocks(op, m, c, r, s, dtype, freeze_group, blocks)
+    m_l, s_l, sctx = m, s, None
+    if _multi_device_mesh():
+        sctx = _sharded_ctx(m, s)
+        if sctx is None:
+            _note_fallback(op, "mesh-no-mapping", (m, c, s))
+            return None
+        mesh, batch_axes, model_axis = sctx
+        m_l = m // _axis_size(mesh, batch_axes)
+        s_l = s // (_axis_size(mesh, (model_axis,)) if model_axis else 1)
+    resolved = resolve_blocks(m_l, c, s_l, vmem_rank or r, dtype, blocks)
+    if resolved is None:
+        _note_fallback(op, "vmem", (m, c, s))
+        return None
+    return resolved, sctx
+
+
 def lowrank_apply(
     x: jax.Array,
     u: jax.Array,
@@ -395,41 +471,19 @@ def lowrank_apply(
     m = 1
     for d in lead:
         m *= d
-    use = use_kernel if use_kernel is not None else (kernel_available() or interpret)
-    if autotune and use:
-        block_m, block_k, block_n = _tuned_blocks(
-            "lowrank_fwd", m, c, r, s, x.dtype, freeze_group,
-            (block_m, block_k, block_n))
-    if use and _multi_device_mesh():
-        # Multi-device mesh: the bare pallas_call would be replicated by
-        # the partitioner (gathering every operand, frozen factors
-        # included); run it under shard_map when a mapping resolves and
-        # the LOCAL shapes divide the blocks, else take the jnp path,
-        # which the partitioner splits natively — NEVER the bare kernel.
-        sctx = _sharded_ctx(m, s)
+    plan = _kernel_plan("lowrank_fwd", m, c, r, s, x.dtype,
+                        (block_m, block_k, block_n), use_kernel=use_kernel,
+                        interpret=interpret, autotune=autotune,
+                        freeze_group=freeze_group)
+    if plan is not None:
+        (bm, bk, bn), sctx = plan
         if sctx is not None:
-            mesh, batch_axes, model_axis = sctx
-            m_l = m // _axis_size(mesh, batch_axes)
-            s_l = s // (_axis_size(mesh, (model_axis,)) if model_axis else 1)
-            if _divisible(m_l, c, s_l, block_m, block_k, block_n):
-                y = _lowrank_sharded(x.reshape(m, c), u, v, mesh, batch_axes,
-                                     model_axis, block_m, block_k, block_n,
-                                     interpret, freeze_group)
-                return y.reshape(*lead, s)
-            _note_fallback("lowrank_fwd", "mesh-indivisible-local", (m, c, s))
+            y = _lowrank_sharded(x.reshape(m, c), u, v, *sctx, bm, bk, bn,
+                                 interpret, freeze_group)
         else:
-            _note_fallback("lowrank_fwd", "mesh-no-mapping", (m, c, s))
-    elif use and _divisible(m, c, s, block_m, block_k, block_n):
-        y = lowrank_matmul_vjp(x.reshape(m, c), u, v,
-                               block_m, block_k, block_n, interpret,
-                               freeze_group, double_buffer)
+            y = lowrank_matmul_vjp(x.reshape(m, c), u, v, bm, bk, bn,
+                                   interpret, freeze_group, double_buffer)
         return y.reshape(*lead, s)
-    elif use:
-        _note_fallback("lowrank_fwd", "indivisible", (m, c, s))
-    else:
-        _note_fallback(
-            "lowrank_fwd",
-            "disabled" if use_kernel is not None else "platform", (m, c, s))
     # One freeze contract on all paths: stop_gradient the frozen factor so
     # a shape-dependent fallback can't silently train it.
     if freeze_group == 0:
@@ -608,38 +662,21 @@ def lowrank_ffn_apply(
     m = 1
     for d in lead:
         m *= d
-    use = use_kernel if use_kernel is not None else (kernel_available() or interpret)
-    if autotune and use:
-        block_m, block_k, block_n = _tuned_blocks(
-            "lowrank_ffn", m, c, gu.shape[1], f, x.dtype, freeze_group,
-            (block_m, block_k, block_n))
-    if use and _multi_device_mesh():
-        # same dispatch contract as lowrank_apply: under a multi-device
-        # mesh the bare kernel path is forbidden — shard_map or jnp.
-        sctx = _sharded_ctx(m, f)
+    # the fused forward holds both branches' rank-r accumulators per tile
+    plan = _kernel_plan("lowrank_ffn", m, c, gu.shape[1], f, x.dtype,
+                        (block_m, block_k, block_n), use_kernel=use_kernel,
+                        interpret=interpret, autotune=autotune,
+                        freeze_group=freeze_group,
+                        vmem_rank=gu.shape[1] + uu.shape[1])
+    if plan is not None:
+        (bm, bk, bn), sctx = plan
         if sctx is not None:
-            mesh, batch_axes, model_axis = sctx
-            m_l = m // _axis_size(mesh, batch_axes)
-            f_l = f // (_axis_size(mesh, (model_axis,)) if model_axis else 1)
-            if _divisible(m_l, c, f_l, block_m, block_k, block_n):
-                y = _lowrank_ffn_sharded(x.reshape(m, c), gu, gv, uu, uv,
-                                         mesh, batch_axes, model_axis,
-                                         block_m, block_k, block_n,
-                                         interpret, freeze_group)
-                return y.reshape(*lead, f)
-            _note_fallback("lowrank_ffn", "mesh-indivisible-local", (m, c, f))
+            y = _lowrank_ffn_sharded(x.reshape(m, c), gu, gv, uu, uv, *sctx,
+                                     bm, bk, bn, interpret, freeze_group)
         else:
-            _note_fallback("lowrank_ffn", "mesh-no-mapping", (m, c, f))
-    elif use and _divisible(m, c, f, block_m, block_k, block_n):
-        y = lowrank_ffn_vjp(x.reshape(m, c), gu, gv, uu, uv,
-                            block_m, block_k, block_n, interpret, freeze_group)
+            y = lowrank_ffn_vjp(x.reshape(m, c), gu, gv, uu, uv,
+                                bm, bk, bn, interpret, freeze_group)
         return y.reshape(*lead, f)
-    elif use:
-        _note_fallback("lowrank_ffn", "indivisible", (m, c, f))
-    else:
-        _note_fallback(
-            "lowrank_ffn",
-            "disabled" if use_kernel is not None else "platform", (m, c, f))
     if freeze_group == 0:
         gu, uu = jax.lax.stop_gradient(gu), jax.lax.stop_gradient(uu)
     elif freeze_group == 1:

@@ -1,14 +1,14 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell with
 ShapeDtypeStruct stand-ins (no allocation), record memory/cost analysis and
 the post-SPMD HLO for the roofline harness.
 
-The XLA_FLAGS assignment above MUST precede every other import (jax locks
-the device count at first init).  One cell per process invocation keeps
-compile state isolated; ``--all`` orchestrates subprocesses with a JSON
-result cache so a failed cell never loses prior progress.
+A CPU-only analysis over 512 fake host devices: ``main`` pins the process
+(and every ``--all`` child) to ``JAX_PLATFORMS=cpu`` with a forced
+512-device host platform before jax first initializes — jax locks the
+device count then — so a dry-run never takes an accelerator.  One cell per
+process invocation keeps compile state isolated; ``--all`` orchestrates
+subprocesses with a JSON result cache so a failed cell never loses prior
+progress.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen2-72b --shape train_4k [--multi-pod]
@@ -19,12 +19,17 @@ import argparse
 import dataclasses
 import functools
 import json
+import os
 import sys
 import time
 import traceback
 from pathlib import Path
 
 DEFAULT_OUT = Path("runs/dryrun")
+
+#: the environment every dry-run process runs under
+HOST_PLATFORM_ENV = {"JAX_PLATFORMS": "cpu",
+                     "XLA_FLAGS": "--xla_force_host_platform_device_count=512"}
 
 
 def build_cell(arch: str, shape_name: str, *, multi_pod: bool, lrd: bool,
@@ -273,7 +278,8 @@ def orchestrate(out_dir: Path, *, multi_pod_list=(False, True), lrd: bool = True
             print(f"[run  ] {stem} ...", flush=True)
             t0 = time.time()
             proc = subprocess.run(cmd, capture_output=True, text=True,
-                                  timeout=timeout_s)
+                                  timeout=timeout_s,
+                                  env=dict(os.environ, **HOST_PLATFORM_ENV))
             dt = time.time() - t0
             if proc.returncode != 0:
                 rec = {"arch": arch, "shape": shape, "mesh": mesh_tag,
@@ -294,6 +300,8 @@ def orchestrate(out_dir: Path, *, multi_pod_list=(False, True), lrd: bool = True
 
 
 def main():
+    # before jax first initializes: this module imports jax only lazily
+    os.environ.update(HOST_PLATFORM_ENV)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

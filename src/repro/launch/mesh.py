@@ -1,17 +1,28 @@
 """Production meshes.  Functions, not module constants — importing this
 module never touches jax device state (required for the dry-run's
-XLA_FLAGS ordering; see dryrun.py).
-
-Mesh construction goes through :func:`repro.compat.make_mesh` so the same
-code runs on jax versions with and without ``jax.sharding.AxisType``."""
+XLA_FLAGS ordering; see dryrun.py)."""
 
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh as make_mesh_compat
+__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh"]
 
-__all__ = ["make_production_mesh", "make_host_mesh", "make_mesh_compat"]
+
+def make_mesh(shape: Sequence[int], names: Sequence[str], devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The sharding rules (``repro.distributed``) place arrays with
+    ``NamedSharding`` and let GSPMD propagate the rest, which needs Auto
+    axes; jax's own default is Explicit.  ``devices`` (optional) builds the
+    mesh over an explicit device subset.
+    """
+    kw = {"devices": devices} if devices is not None else {}
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names), **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,7 +31,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     growing it is how the design scales to N pods (DESIGN.md §5)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -51,5 +62,5 @@ def make_host_mesh(data: int = 1, model: int = 1):
             f"host platform with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             f"(set before jax initializes).")
-    return make_mesh_compat((data, model), ("data", "model"),
-                            devices=jax.devices()[:need])
+    return make_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[:need])

@@ -32,6 +32,7 @@ from pathlib import Path
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import DistConfig, LRDConfig, RunConfig, ShapeConfig
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import EventLog
 from repro.serving import ServeConfig, ServeEngine
 
@@ -111,6 +112,7 @@ def main(argv=None):
                     help="with jsonl, mirror every event to the console")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     obs = None
     if args.obs or args.log_format == "jsonl":
         path = None

@@ -530,6 +530,22 @@ def build_train_step(run: RunConfig, mesh):
     return train_step
 
 
+def compile_train_step(run: RunConfig, mesh, train_step, state: TrainState,
+                       batch, phase: int):
+    """Ahead-of-time compile one freezing phase of ``train_step``.
+
+    Explicit in/out shardings and a DONATED state: ``in_shardings ==
+    out_shardings`` lets every updated buffer alias its predecessor.  The
+    batch shardings come from the batch's actual structure.  Returns the
+    compiled executable, so callers can read its HLO or memory analysis
+    without a second compile."""
+    shs = state_shardings(run, mesh, state)
+    return jax.jit(
+        functools.partial(train_step, phase=phase), donate_argnums=(0,),
+        in_shardings=(shs, batch_shardings(batch, mesh)),
+        out_shardings=(shs, None)).lower(state, batch).compile()
+
+
 # --------------------------------------------------------------------------
 # prefill / decode
 # --------------------------------------------------------------------------

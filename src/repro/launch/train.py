@@ -37,7 +37,6 @@ recipe, XLA_FLAGS=--xla_force_host_platform_device_count=8):
 from __future__ import annotations
 
 import argparse
-import functools
 import time
 from pathlib import Path
 
@@ -53,6 +52,7 @@ from repro.configs.base import (DistConfig, LRDConfig, ObsConfig, OptimConfig,
                                 RunConfig, ShapeConfig)
 from repro.data import LMBatchIterator
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.obs import EventLog
 from repro.optim.optimizers import OptState
@@ -129,7 +129,9 @@ def build_run(args) -> RunConfig:
     )
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The driver's command line; ``build_run(build_parser().parse_args(
+    argv))`` is the RunConfig a ``train.py argv`` run trains with."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--smoke", action="store_true",
@@ -192,8 +194,13 @@ def main(argv=None):
     ap.add_argument("--profile-steps", default="",
                     help="START:STOP — jax.profiler trace window over "
                          "these steps (saved under the obs dir)")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
+    enable_compile_cache()
     run = build_run(args)
     if args.mesh == "production":
         mesh = make_production_mesh()
@@ -276,20 +283,12 @@ def main(argv=None):
     sync_cache = {}  # phase -> compiled step's cross-device sync bytes
 
     def fn_for(phase: int, batch):
-        # one executable per phase, with explicit shardings: the state is
-        # DONATED, so in_shardings == out_shardings lets every updated
-        # buffer alias its predecessor.  Batch shardings are derived from
-        # the iterator's actual structure, not the family's full spec set.
-        # Compiled ahead of time so the telemetry layer can read the
-        # optimized HLO off the same executable the loop runs (no second
-        # compile for the per-phase sync-bytes attribution).
+        # one executable per phase, compiled ahead of time so the telemetry
+        # layer can read the optimized HLO off the same executable the loop
+        # runs (no second compile for the per-phase sync-bytes attribution)
         if phase not in step_fns:
-            shs = steps_mod.state_shardings(run, mesh, state)
-            compiled = jax.jit(
-                functools.partial(train_step, phase=phase),
-                donate_argnums=(0,),
-                in_shardings=(shs, steps_mod.batch_shardings(batch, mesh)),
-                out_shardings=(shs, None)).lower(state, batch).compile()
+            compiled = steps_mod.compile_train_step(run, mesh, train_step,
+                                                    state, batch, phase)
             step_fns[phase] = compiled
             if run.obs.enabled:
                 total, per = ((0, {}) if mesh.devices.size <= 1 else
@@ -341,11 +340,10 @@ def main(argv=None):
                 obs.emit("rank_adapt", epoch=epoch, boundary=boundary,
                          shrunk=shrunk, rank_map=cur_ranks)
         if run.obs.profile_start == step and not profiling:
-            try:
-                jax.profiler.start_trace(prof_dir)
-                profiling = True
-            except Exception as e:  # profiler backend unavailable
-                print(f"[profile] start_trace failed: {e}")
+            # a requested trace window that cannot start is an error: a
+            # run without its trace is not the run that was asked for
+            jax.profiler.start_trace(prof_dir)
+            profiling = True
         batch = steps_mod.shard_batch(next(it), mesh)
         t0 = time.perf_counter()
         state, metrics = fn_for(phase, batch)(state, batch)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed import shard
+from repro.kernels import ops as kops
 from repro.models import common
 from repro.models.common import Params, apply_rope, linear, rmsnorm, rmsnorm_init
 
@@ -159,9 +160,12 @@ def blockwise_attention(q, k, v, *, causal: bool, block_q: int, block_kv: int) -
     return out.reshape(b, sq, h, dv)
 
 
-def attention_core(q, k, v, cfg: ModelConfig, *, causal: bool) -> jax.Array:
+def attention_core(q, k, v, cfg: ModelConfig, *, causal: bool,
+                   interpret: bool = False) -> jax.Array:
+    """``interpret`` runs the flash kernel in Pallas interpret mode (the
+    kernel policy's flag, for CPU validation)."""
     if cfg.attention_impl == "flash":
-        out = _flash_path(q, k, v, cfg, causal=causal)
+        out = _flash_path(q, k, v, cfg, causal=causal, interpret=interpret)
         if out is not None:
             return out
     if cfg.attention_impl == "dense" or q.shape[1] <= cfg.attention_block_q:
@@ -171,15 +175,14 @@ def attention_core(q, k, v, cfg: ModelConfig, *, causal: bool) -> jax.Array:
                                block_kv=cfg.attention_block_kv)
 
 
-def _flash_path(q, k, v, cfg: ModelConfig, *, causal: bool):
+def _flash_path(q, k, v, cfg: ModelConfig, *, causal: bool, interpret: bool):
     """Pallas flash-attention (opt-in, attention_impl='flash').
 
     KV heads are broadcast to Q heads (GQA grouping handled outside the
-    kernel); falls back to blockwise when shapes don't tile. Interpret mode
-    runs off-TPU so the path is CPU-testable.
+    kernel); falls back to blockwise when shapes don't tile.  ``interpret``
+    (the kernel policy's flag) runs the kernel on CPU for validation.
     """
     from repro.kernels.flash_attention import flash_attention
-    from repro.kernels.ops import kernel_available
 
     b, sq, h, d = q.shape
     sk, kvh = k.shape[1], k.shape[2]
@@ -196,7 +199,7 @@ def _flash_path(q, k, v, cfg: ModelConfig, *, causal: bool):
     k2 = jnp.swapaxes(kb, 1, 2).reshape(b * h, sk, d)
     v2 = jnp.swapaxes(vb, 1, 2).reshape(b * h, sk, v.shape[-1])
     out = flash_attention(q2, k2, v2, causal=causal, block_q=bq, block_kv=bkv,
-                          interpret=not kernel_available())
+                          interpret=interpret)
     return jnp.swapaxes(out.reshape(b, h, sq, v.shape[-1]), 1, 2)
 
 
@@ -382,7 +385,8 @@ def gqa_apply(
         q = shard(q, "batch", "seq", "heads", None)
         k = shard(k, "batch", "kv_seq", "kv_heads", None)
         v = shard(v, "batch", "kv_seq", "kv_heads", None)
-        out = attention_core(q, k, v, cfg, causal=causal and not cross)
+        out = attention_core(q, k, v, cfg, causal=causal and not cross,
+                             interpret=kops.as_policy(use_pallas).interpret)
         new_cache = {"k": k, "v": v} if not cross else {"k": k, "v": v}
     else:  # decode: s == 1 (plain) or k+1 (verify chunk); cache (B, Smax, KV, hd)
         assert cache is not None and pos is not None
@@ -404,7 +408,6 @@ def gqa_apply(
             # in-chunk causal mask of the speculative verify step.
             length = (base[:, None].astype(jnp.int32) + 1
                       + jnp.arange(s, dtype=jnp.int32)[None, :])
-            from repro.kernels import ops as kops
             native_int8 = kops.as_policy(use_pallas).int8_decode == "native"
             if "page_table" in cache:  # paged block pool (DESIGN.md §8)
                 if rows is None:
@@ -525,7 +528,8 @@ def mla_apply(
         q = shard(q, "batch", "seq", "heads", None)
         k = shard(k, "batch", "kv_seq", "heads", None)
         v = shard(v, "batch", "kv_seq", "heads", None)
-        out = attention_core(q, k, v, cfg, causal=True)
+        out = attention_core(q, k, v, cfg, causal=True,
+                             interpret=kops.as_policy(use_pallas).interpret)
         new_cache = {"ckv": ckv, "kr": k_rope[..., 0, :]}
     else:
         # Absorbed decode: score in latent space, never materialize per-head K/V.

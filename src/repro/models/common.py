@@ -40,7 +40,7 @@ def linear(p: Params, x: jax.Array, *,
         u, v = p["u"], p["v"]
         if pol.use_pallas:
             y = kops.lowrank_apply(
-                x, u, v, interpret=pol.interpret,
+                x, u, v, use_kernel=True, interpret=pol.interpret,
                 block_m=pol.block_m, block_k=pol.block_k, block_n=pol.block_n,
                 freeze_group=pol.freeze_group, autotune=pol.autotune,
                 double_buffer=pol.double_buffer)
@@ -207,10 +207,10 @@ def ffn(p: Params, x: jax.Array, *,
                 and "bias" not in p["gate"] and "bias" not in p["up"]):
             # Both branches factorized: one fused SwiGLU-first-half kernel —
             # the rank-r intermediates AND the two (M, F) branch outputs stay
-            # in VMEM (falls back internally on indivisible shapes).
+            # in VMEM.
             h = kops.lowrank_ffn_apply(
                 x, p["gate"]["u"], p["gate"]["v"], p["up"]["u"], p["up"]["v"],
-                interpret=pol.interpret, block_m=pol.block_m,
+                use_kernel=True, interpret=pol.interpret, block_m=pol.block_m,
                 block_k=pol.block_k, block_n=pol.block_n,
                 freeze_group=pol.freeze_group, autotune=pol.autotune)
         else:

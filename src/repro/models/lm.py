@@ -19,7 +19,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core.decompose import Decomposer
 from repro.distributed import shard
@@ -112,7 +111,7 @@ def _scan_stack(stacked: Params, h: jax.Array, body, cache: Optional[Params],
         # Barrier keeps the remat stash in the carry's own dtype (bf16):
         # without it XLA's convert-sinking stores an extra fp32 copy of
         # every layer input (measured 2x stash memory on the dry-run).
-        carry = compat.optimization_barrier(carry)
+        carry = jax.lax.optimization_barrier(carry)
         h_new, new_lc, aux = body(lp, carry, lc)
         return h_new, (new_lc, aux)
 
@@ -130,7 +129,7 @@ def _scan_stack(stacked: Params, h: jax.Array, body, cache: Optional[Params],
             @functools.partial(jax.checkpoint, prevent_cse=False)
             def group_body(carry, xs):
                 gp, gc = xs
-                carry = compat.optimization_barrier(carry)
+                carry = jax.lax.optimization_barrier(carry)
                 h_new, ys = jax.lax.scan(inner_body, carry, (gp, gc))
                 return h_new, ys
 

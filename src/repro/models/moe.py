@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import ModelConfig
 from repro.distributed import current_mesh, shard
 from repro.models import common

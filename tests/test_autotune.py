@@ -193,11 +193,14 @@ def test_predicted_vs_measured_candidate_ordering(m, c, r, s):
             for cand in cands]
     assert pred == sorted(pred)  # ordered best-predicted-first
     top = cands[:3]
-    meas = []
-    for cand in top:
-        sec, fb = autotune.measure_candidate("lowrank_fwd", m, c, r, s,
-                                             jnp.float32, cand,
-                                             interpret=True, iters=2, warmup=1)
-        assert not fb  # interpret mode really ran the kernel
-        meas.append(sec)
+    # Rounds interleave the candidates, so a burst of other work on the
+    # host lands on all of them alike, and each keeps its quickest round.
+    meas = [float("inf")] * len(top)
+    for _ in range(3):
+        for i, cand in enumerate(top):
+            sec, fb = autotune.measure_candidate(
+                "lowrank_fwd", m, c, r, s, jnp.float32, cand,
+                interpret=True, iters=2, warmup=1)
+            assert not fb  # interpret mode really ran the kernel
+            meas[i] = min(meas[i], sec)
     assert meas[0] <= 3.0 * min(meas)

@@ -28,7 +28,7 @@ import jax, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import load_checkpoint
 from repro.checkpoint.store import latest_checkpoint
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((2, 4), ("data", "model"))
 shardings = {{

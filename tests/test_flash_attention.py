@@ -53,12 +53,14 @@ def test_flash_attention_skips_future_blocks_exactly():
 
 
 def test_flash_impl_matches_blockwise_in_model():
-    """attention_impl='flash' (Pallas, interpret off-TPU) must equal the
-    blockwise jnp path end-to-end through a GQA model forward."""
+    """attention_impl='flash' (Pallas, interpret mode by the kernel
+    policy) must equal the blockwise jnp path end-to-end through a GQA
+    model forward."""
     import dataclasses
 
     from repro.configs import get_smoke_config
     from repro.configs.base import DistConfig, LRDConfig, RunConfig, ShapeConfig
+    from repro.kernels.ops import KernelPolicy
     from repro.launch import steps
     from repro.models import lm as lm_mod
 
@@ -73,6 +75,7 @@ def test_flash_impl_matches_blockwise_in_model():
     params, _ = steps.init_params(run, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, cfg.vocab_size)
     lb, _, _ = lm_mod.lm_apply(params, toks, cfg_b, mode="full")
-    lf, _, _ = lm_mod.lm_apply(params, toks, cfg_f, mode="full")
+    lf, _, _ = lm_mod.lm_apply(params, toks, cfg_f, mode="full",
+                               use_pallas=KernelPolicy(interpret=True))
     np.testing.assert_allclose(np.asarray(lb), np.asarray(lf), rtol=1e-4,
                                atol=1e-4)

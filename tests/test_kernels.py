@@ -41,7 +41,8 @@ def test_lowrank_matmul_matches_ref(m, c, r, s, bm, bk, bn, dtype):
 
 
 def test_lowrank_apply_batched_and_fallback():
-    # 3-D input routes through reshape; indivisible shapes hit the jnp path
+    # 3-D input routes through reshape; dims no requested block divides run
+    # as whole-dimension blocks
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 100, 130), jnp.float32)
     u = jax.random.normal(jax.random.PRNGKey(1), (130, 16), jnp.float32) * 0.1
     v = jax.random.normal(jax.random.PRNGKey(2), (16, 70), jnp.float32) * 0.2
@@ -147,3 +148,19 @@ def test_double_buffered_dx_matches_standard(dtype):
     db = lowrank_matmul_dx(dy, u, v, block_m=128, block_k=256, block_n=128,
                            interpret=True, double_buffer=True)
     np.testing.assert_array_equal(np.asarray(std), np.asarray(db))
+
+
+@pytest.mark.parametrize("m,c,s,r,dtype,want", [
+    # smollm-360m train step: q/o keep block_m, take whole 960 for k and n
+    (8192, 960, 960, 239, jnp.bfloat16, (256, 960, 960)),
+    # down projection: block_k 512 divides 2560, block_n whole 960
+    (8192, 2560, 960, 256, jnp.bfloat16, (256, 512, 960)),
+    # decode batch of 8 rows against the 320-wide k/v projection
+    (8, 960, 320, 80, jnp.bfloat16, (8, 960, 320)),
+    # no requested block divides and whole-dimension tiles overflow VMEM
+    (2000, 1100, 6, 3, jnp.float32, None),
+])
+def test_resolve_blocks_rule(m, c, s, r, dtype, want):
+    """Requested blocks stay where they divide; otherwise the whole
+    dimension is the block, unless its tiles overflow the VMEM budget."""
+    assert ops.resolve_blocks(m, c, s, r, dtype, (256, 512, 256)) == want

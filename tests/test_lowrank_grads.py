@@ -5,8 +5,9 @@ The fused forward kernels pair with Pallas backward kernels through a
 
 * dx/dU/dV from the kernel path == ``jax.grad`` of the jnp reference
   composition (kernels/ref.py), to <= 1e-4 in f32;
-* non-block-divisible shapes fall back to the reference path and still
-  differentiate;
+* non-block-divisible shapes run whole-dimension blocks, and a shape no
+  block fits in VMEM falls back to the reference path and still honors
+  the freeze contract;
 * a static ``freeze_group`` makes the frozen factor's gradient *symbolically
   absent* — its backward kernel does not appear in the jaxpr (checked with
   ``jax.make_jaxpr``), as opposed to emitted-then-DCE'd — and the same holds
@@ -14,6 +15,7 @@ The fused forward kernels pair with Pallas backward kernels through a
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -37,20 +39,21 @@ def _grads(fn, *args):
     return jax.grad(fn, argnums=tuple(range(len(args))))(*args)
 
 
-def _kernel_names(jaxpr) -> str:
-    """Flat text of the jaxpr — Pallas kernels appear by kernel-fn name."""
-    return str(jaxpr)
+def _kernel_names(jaxpr) -> set:
+    """Names of the Pallas kernels in a jaxpr (the stable ``name=`` every
+    ``pallas_call`` in ``repro.kernels`` passes)."""
+    return set(re.findall(r"name=(\w+)", str(jaxpr)))
 
 
-# (m, c, r, s, bm, bk, bn); last two are NOT divisible by the blocks and
-# must take the reference fallback.
+# (m, c, r, s, bm, bk, bn); the last two are NOT divisible by the requested
+# blocks: the dispatcher resolves whole-dimension blocks for those dims.
 SHAPES = [
     (256, 512, 64, 256, 128, 256, 128),
     (512, 1024, 128, 512, 256, 512, 256),
     (256, 512, 96, 384, 128, 256, 128),   # r, s off the MXU-tile grid
     (128, 256, 32, 128, 128, 256, 128),
-    (100, 130, 16, 70, 128, 256, 128),    # indivisible -> jnp fallback
-    (192, 512, 64, 256, 128, 256, 128),   # m indivisible by bm -> fallback
+    (100, 130, 16, 70, 128, 256, 128),    # no block divides -> whole dims
+    (192, 512, 64, 256, 128, 256, 128),   # m indivisible by bm -> whole m
 ]
 
 
@@ -109,9 +112,10 @@ def test_lowrank_matmul_freeze_group_grads(freeze_group):
 @pytest.mark.parametrize("freeze_group", [0, 1])
 @interpret
 def test_freeze_group_honored_on_fallback_path(freeze_group):
-    """Indivisible shapes take the jnp fallback — the freeze contract must
-    hold there too (stop_gradient), not only on the kernel path."""
-    m, c, r, s = 100, 130, 16, 70  # indivisible by any default block
+    """A shape whose whole-dimension tiles overflow the VMEM budget takes
+    the jnp fallback — the freeze contract must hold there too
+    (stop_gradient), not only on the kernel path."""
+    m, c, r, s = 2000, 1100, 16, 70  # no default block divides; too big whole
     x, u, v = _mats(jax.random.PRNGKey(21), m, c, r, s, jnp.float32)
 
     def f(x, u, v):
@@ -119,7 +123,9 @@ def test_freeze_group_honored_on_fallback_path(freeze_group):
                               freeze_group=freeze_group)
         return jnp.sum(y ** 2)
 
-    dx, du, dv = _grads(f, x, u, v)
+    with ops.capture_fallbacks() as sink:
+        dx, du, dv = _grads(f, x, u, v)
+    assert [fb.reason for fb in sink] == ["vmem"]
     frozen = du if freeze_group == 0 else dv
     live = dv if freeze_group == 0 else du
     assert float(jnp.abs(frozen).max()) == 0.0
@@ -144,15 +150,15 @@ def test_frozen_factor_kernel_not_emitted():
 
     both = _kernel_names(jax.make_jaxpr(
         jax.grad(loss_for(None), argnums=(0, 1, 2)))(x, u, v))
-    assert "_du_kernel" in both and "_dv_kernel" in both and "_dx_kernel" in both
+    assert {"lowrank_du", "lowrank_dv", "lowrank_dx"} <= both
 
     fz0 = _kernel_names(jax.make_jaxpr(
         jax.grad(loss_for(0), argnums=(0, 1, 2)))(x, u, v))
-    assert "_du_kernel" not in fz0 and "_dv_kernel" in fz0
+    assert "lowrank_du" not in fz0 and "lowrank_dv" in fz0
 
     fz1 = _kernel_names(jax.make_jaxpr(
         jax.grad(loss_for(1), argnums=(0, 1, 2)))(x, u, v))
-    assert "_dv_kernel" not in fz1 and "_du_kernel" in fz1
+    assert "lowrank_dv" not in fz1 and "lowrank_du" in fz1
 
 
 @pytest.mark.parametrize("freeze_group", [None, 0, 1])
@@ -217,19 +223,20 @@ def test_train_step_jaxpr_elides_frozen_factor_kernels():
 
     def jaxpr_for(phase):
         state, _ = steps.make_train_state(run.optim, params, phase)
-        return str(jax.make_jaxpr(functools.partial(train, phase=phase))(
-            state, batch))
+        return _kernel_names(jax.make_jaxpr(
+            functools.partial(train, phase=phase))(state, batch))
 
     unfrozen = jaxpr_for(-1)
-    assert "_kernel" in unfrozen  # fused forward actually on the hot path
-    assert "_du_kernel" in unfrozen and "_dv_kernel" in unfrozen
+    # fused forwards actually on the hot path
+    assert {"lowrank_fwd", "lowrank_ffn_fwd"} <= unfrozen
+    assert "lowrank_du" in unfrozen and "lowrank_dv" in unfrozen
 
     phase0 = jaxpr_for(0)  # group 0 (u) frozen
-    assert "_du_kernel" not in phase0 and "_dv_kernel" in phase0
-    assert "_dx_kernel" in phase0
+    assert "lowrank_du" not in phase0 and "lowrank_dv" in phase0
+    assert "lowrank_dx" in phase0
 
     phase1 = jaxpr_for(1)  # group 1 (v) frozen
-    assert "_dv_kernel" not in phase1 and "_du_kernel" in phase1
+    assert "lowrank_dv" not in phase1 and "lowrank_du" in phase1
 
 
 @interpret

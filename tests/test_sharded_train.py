@@ -307,7 +307,7 @@ jx = jax.make_jaxpr(jax.grad(
     lambda v: jnp.sum(apply_sharded(kx, ku, v, fg=0) ** 2)))(kv)
 psums = psum_eqns(jx.jaxpr)
 assert (C, R) not in [s for _, s in psums], psums
-assert "_du_kernel" not in str(jx)
+assert "name=lowrank_du" not in str(jx) and "name=lowrank_dv" in str(jx)
 print("KERNEL_SHMAP_OK")
 
 # ---- elastic resume 1-device -> 8-device, loss parity ---------------------

@@ -284,8 +284,10 @@ def test_serving_engine_generates():
 
 
 def test_grad_compression_quantize_accuracy():
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+
     from repro.distributed.compression import _quantize_pmean
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1,), ("pod",))
     g = jax.random.normal(jax.random.PRNGKey(0), (64, 64)) * 0.01
