@@ -19,6 +19,8 @@ from typing import Dict, Iterator, Tuple
 
 import numpy as np
 
+from repro.obs import span
+
 
 def _rng_for(step: int, shard: int, seed: int) -> np.random.Generator:
     # counter-based: state is (seed, shard, step) — no mutable RNG to persist
@@ -43,20 +45,22 @@ class SyntheticLM:
         self._tables = rng.integers(0, v, size=(8, v, 4))
 
     def next_batch(self) -> Dict[str, np.ndarray]:
-        rng = _rng_for(self.step, self.shard, self.seed)
-        b, s = self.batch_per_host, self.seq_len
-        topics = rng.integers(0, 8, size=(b,))
-        toks = np.empty((b, s + 1), np.int32)
-        toks[:, 0] = rng.integers(0, self._v, size=(b,))
-        choice = rng.integers(0, 4, size=(b, s))
-        noise = rng.random((b, s)) < 0.1
-        rand_tok = rng.integers(0, self._v, size=(b, s))
-        for t in range(s):
-            nxt = self._tables[topics, toks[:, t], choice[:, t]]
-            toks[:, t + 1] = np.where(noise[:, t], rand_tok[:, t], nxt)
-        self.step += 1
-        return {"tokens": toks[:, :-1].astype(np.int32),
-                "labels": toks[:, 1:].astype(np.int32)}
+        """The next host batch (a ``data.batch`` span on the trace)."""
+        with span("data.batch"):
+            rng = _rng_for(self.step, self.shard, self.seed)
+            b, s = self.batch_per_host, self.seq_len
+            topics = rng.integers(0, 8, size=(b,))
+            toks = np.empty((b, s + 1), np.int32)
+            toks[:, 0] = rng.integers(0, self._v, size=(b,))
+            choice = rng.integers(0, 4, size=(b, s))
+            noise = rng.random((b, s)) < 0.1
+            rand_tok = rng.integers(0, self._v, size=(b, s))
+            for t in range(s):
+                nxt = self._tables[topics, toks[:, t], choice[:, t]]
+                toks[:, t + 1] = np.where(noise[:, t], rand_tok[:, t], nxt)
+            self.step += 1
+            return {"tokens": toks[:, :-1].astype(np.int32),
+                    "labels": toks[:, 1:].astype(np.int32)}
 
     def state_dict(self) -> Dict[str, int]:
         return {"step": self.step, "shard": self.shard, "seed": self.seed}

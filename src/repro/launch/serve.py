@@ -33,7 +33,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.configs.base import DistConfig, LRDConfig, RunConfig, ShapeConfig
 from repro.launch import steps as steps_mod
 from repro.launch.compile_cache import enable_compile_cache
-from repro.obs import EventLog
+from repro.obs import EventLog, install_host_hooks, uninstall_host_hooks
 from repro.serving import ServeConfig, ServeEngine
 
 
@@ -156,9 +156,13 @@ def main(argv=None):
             obs.emit("run_start", _mirror=False, kind="serve",
                      arch=cfg.name, slots=args.slots,
                      requests=args.requests, rate=args.rate)
+        if args.obs:  # garbage-collection spans and counters
+            install_host_hooks()
         t0 = time.perf_counter()
         outs = engine.serve(trace)
         dt = time.perf_counter() - t0
+        if args.obs:
+            uninstall_host_hooks()
         stats = engine.scheduler.latency_stats()
         if obs is not None:
             obs.emit("run_end", _mirror=False, kind="serve", **stats)

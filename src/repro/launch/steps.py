@@ -51,8 +51,13 @@ from repro.distributed.compression import value_and_grad_compressed
 from repro.kernels.ops import KernelPolicy
 from repro.models import encdec as encdec_mod, lm
 from repro.models.common import cross_entropy
+from repro.obs import scope, span, watch_compiles
 from repro.optim import init_moments, init_optimizer
 from repro.optim.optimizers import OptState, apply_updates
+
+# every program this module builds is compiled through jax: count them
+# (jit_compiles / jit_compile_s, DESIGN.md §12)
+watch_compiles()
 
 
 class TrainState(NamedTuple):
@@ -164,41 +169,63 @@ def repartition_state(optim_cfg, state: TrainState, parked, new_phase: int,
     factors are fresh arrays, so its params AND moments are re-placed by
     group path (``distributed.place_at_paths``), re-resolving divisibility
     at the new ranks.
+
+    On the trace the swap is a ``freeze.swap`` span holding one span per
+    stage: ``freeze.merge``, ``freeze.rank_adapt`` (with an active
+    schedule), ``freeze.partition``, ``freeze.place`` (on a mesh),
+    ``freeze.unpark`` and ``freeze.park``.
     """
-    old_phase = freezing.phase_of_partition(state.trainable, state.frozen)
-    params = freezing.merge(state.trainable, state.frozen)
-    moments = freezing.merge_moments((state.opt.mu, state.opt.nu), parked)
+    with span("freeze.swap", phase=new_phase):
+        return _repartition(optim_cfg, state, parked, new_phase, mesh, run,
+                            schedule, boundary)
+
+
+def _repartition(optim_cfg, state, parked, new_phase, mesh, run, schedule,
+                 boundary):
+    """:func:`repartition_state`'s body, one ``freeze.*`` span per stage."""
+    with span("freeze.merge"):
+        old_phase = freezing.phase_of_partition(state.trainable, state.frozen)
+        params = freezing.merge(state.trainable, state.frozen)
+        moments = freezing.merge_moments((state.opt.mu, state.opt.nu), parked)
     trunc = {}
     if schedule is not None and schedule.active:
-        trunc = rank_adapt.plan_rank_map(params, schedule, boundary)
-        if trunc:
-            params = rank_adapt.truncate_params(params, trunc)
-            moments = rank_adapt.slice_moments(moments, trunc)
-    trainable, frozen = freezing.partition(params, new_phase)
-    active, parked = freezing.partition_moments(moments, new_phase)
+        with span("freeze.rank_adapt"):
+            trunc = rank_adapt.plan_rank_map(params, schedule, boundary)
+            if trunc:
+                params = rank_adapt.truncate_params(params, trunc)
+                moments = rank_adapt.slice_moments(moments, trunc)
+    with span("freeze.partition"):
+        trainable, frozen = freezing.partition(params, new_phase)
+        active, parked = freezing.partition_moments(moments, new_phase)
     if mesh is None or mesh.devices.size <= 1:
-        opt = OptState(state.opt.step, *(_unpark(t) for t in active))
-        return (TrainState(trainable, frozen, opt),
-                tuple(_park(t) for t in parked))
+        with span("freeze.unpark"):
+            opt = OptState(state.opt.step, *(_unpark(t) for t in active))
+        with span("freeze.park"):
+            parked = tuple(_park(t) for t in parked)
+        return TrainState(trainable, frozen, opt), parked
 
     prm = _param_rules(run) if run is not None else PARAM_RULES
     opt_rules = _opt_rules(run) if run is not None else prm
     moved = freezing.groups_to_replace(old_phase, new_phase)
-    trainable = _place_moved(trainable, named_shardings(trainable, mesh, prm),
-                             moved)
-    frozen = _place_moved(frozen,
-                          named_shardings(frozen, mesh, FROZEN_PARAM_RULES),
-                          moved)
-    mu, nu = (_unpark(t, mesh, opt_rules) for t in active)
+    with span("freeze.place"):
+        trainable = _place_moved(
+            trainable, named_shardings(trainable, mesh, prm), moved)
+        frozen = _place_moved(
+            frozen, named_shardings(frozen, mesh, FROZEN_PARAM_RULES), moved)
+    with span("freeze.unpark"):
+        mu, nu = (_unpark(t, mesh, opt_rules) for t in active)
     if trunc:
-        paths = tuple(trunc)
-        trainable = place_at_paths(trainable, mesh, prm, paths)
-        frozen = place_at_paths(frozen, mesh, FROZEN_PARAM_RULES, paths)
-        mu = place_at_paths(mu, mesh, opt_rules, paths)
-        if nu != ():
-            nu = place_at_paths(nu, mesh, opt_rules, paths)
+        with span("freeze.place"):
+            paths = tuple(trunc)
+            trainable = place_at_paths(trainable, mesh, prm, paths)
+            frozen = place_at_paths(frozen, mesh, FROZEN_PARAM_RULES, paths)
+            mu = place_at_paths(mu, mesh, opt_rules, paths)
+            if nu != ():
+                nu = place_at_paths(nu, mesh, opt_rules, paths)
     opt = OptState(state.opt.step, mu, nu)
-    return TrainState(trainable, frozen, opt), tuple(_park(t) for t in parked)
+    with span("freeze.park"):
+        parked = tuple(_park(t) for t in parked)
+    return TrainState(trainable, frozen, opt), parked
 
 
 def _place_moved(tree, shardings, moved_groups, name: str = ""):
@@ -256,9 +283,10 @@ def batch_shardings(batch, mesh):
 
 def shard_batch(batch, mesh):
     """device_put a (host) batch with its DP sharding — the per-step data
-    placement of the sharded train loop."""
-    return jax.tree_util.tree_map(jax.device_put, batch,
-                                  batch_shardings(batch, mesh))
+    placement of the sharded train loop (a ``data.shard`` span)."""
+    with span("data.shard"):
+        return jax.tree_util.tree_map(jax.device_put, batch,
+                                      batch_shardings(batch, mesh))
 
 
 def make_sharded_train_state(run: RunConfig, params, phase: int, mesh):
@@ -416,16 +444,20 @@ def _loss_fn(trainable, frozen, batch, run: RunConfig, phase: int):
     logits, _, aux, hidden = _forward_full(params, batch, run,
                                            return_hidden=need_h, mode="train",
                                            phase=phase)
-    loss = cross_entropy(logits, batch["labels"])
+    with scope("loss"):
+        loss = cross_entropy(logits, batch["labels"])
     if cfg.use_mtp:
         mtp_lg = lm.mtp_logits(params, hidden, batch["tokens"], cfg,
                                use_pallas=kernel_policy(run, phase))
-        # padded shift-by-one: predict labels shifted left, mask last 2 slots
-        mtp_labels = jnp.roll(batch["labels"], -1, axis=1)
-        loss = loss + cfg.mtp_loss_weight * cross_entropy(
-            mtp_lg, mtp_labels, mask=lm.mtp_loss_mask(batch["tokens"]))
+        with scope("loss"):
+            # padded shift-by-one: predict labels shifted left, mask the
+            # last 2 slots
+            mtp_labels = jnp.roll(batch["labels"], -1, axis=1)
+            loss = loss + cfg.mtp_loss_weight * cross_entropy(
+                mtp_lg, mtp_labels, mask=lm.mtp_loss_mask(batch["tokens"]))
     if cfg.num_experts:
-        loss = loss + 0.01 * aux
+        with scope("loss"):
+            loss = loss + 0.01 * aux
     return loss
 
 
@@ -516,14 +548,15 @@ def build_train_step(run: RunConfig, mesh):
                         lambda g, sp: jax.lax.with_sharding_constraint(
                             g, NamedSharding(mesh, sp)), grads, gspecs)
 
-            new_trainable, new_opt = apply_updates(run.optim, state.trainable,
-                                                   grads, state.opt)
-            # square in the grad dtype, accumulate in f32: a f32 pre-cast
-            # materializes a full fp32 copy of every grad leaf at once
-            # (measured +5 GiB/device on deepseek-v3).
-            gnorm = jnp.sqrt(sum(
-                jnp.sum(jnp.square(g), dtype=jnp.float32)
-                for g in jax.tree_util.tree_leaves(grads)))
+            with scope("optim"):
+                new_trainable, new_opt = apply_updates(
+                    run.optim, state.trainable, grads, state.opt)
+                # square in the grad dtype, accumulate in f32: a f32
+                # pre-cast materializes a full fp32 copy of every grad leaf
+                # at once (measured +5 GiB/device on deepseek-v3).
+                gnorm = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g), dtype=jnp.float32)
+                    for g in jax.tree_util.tree_leaves(grads)))
             return (TrainState(new_trainable, state.frozen, new_opt),
                     {"loss": loss, "grad_norm": gnorm})
 

@@ -37,6 +37,7 @@ recipe, XLA_FLAGS=--xla_force_host_platform_device_count=8):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 from pathlib import Path
 
@@ -54,7 +55,7 @@ from repro.data import LMBatchIterator
 from repro.launch import steps as steps_mod
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
-from repro.obs import EventLog
+from repro.obs import EventLog, install_host_hooks, uninstall_host_hooks
 from repro.optim.optimizers import OptState
 
 
@@ -220,6 +221,10 @@ def main(argv=None):
         obs_dir.mkdir(parents=True, exist_ok=True)
     obs = EventLog(obs_dir / "events.jsonl" if run.obs.enabled else None,
                    mirror=print, fmt=run.obs.log_format)
+    # garbage-collection spans and counters only while tracing is wanted
+    host_hooks = run.obs.enabled or run.obs.profile_start >= 0
+    if host_hooks:
+        install_host_hooks()
 
     params, plan = steps_mod.init_params(run)
     if run.lrd.enabled:
@@ -309,6 +314,19 @@ def main(argv=None):
     tokens_per_step = run.shape.global_batch * run.shape.seq_len
     profiling = False
     prof_dir = str(obs_dir / "profile")
+
+    def end_profile(stop_step: int) -> None:
+        nonlocal profiling
+        jax.profiler.stop_trace()
+        profiling = False
+        obs.emit("profile_window", start_step=run.obs.profile_start,
+                 stop_step=stop_step, trace_dir=prof_dir)
+
+    def finish() -> None:
+        if host_hooks:
+            uninstall_host_hooks()
+        obs.close()
+
     for step in range(start_step, args.steps):
         epoch = step // args.steps_per_epoch
         phase = phase_at(step)
@@ -344,17 +362,17 @@ def main(argv=None):
             # run without its trace is not the run that was asked for
             jax.profiler.start_trace(prof_dir)
             profiling = True
-        batch = steps_mod.shard_batch(next(it), mesh)
-        t0 = time.perf_counter()
-        state, metrics = fn_for(phase, batch)(state, batch)
-        loss = float(metrics["loss"])
-        dt = time.perf_counter() - t0
+        # inside a trace window each step is one profiler step
+        with (jax.profiler.StepTraceAnnotation("train", step_num=step)
+              if profiling else contextlib.nullcontext()):
+            batch = steps_mod.shard_batch(next(it), mesh)
+            t0 = time.perf_counter()
+            state, metrics = fn_for(phase, batch)(state, batch)
+            loss = float(metrics["loss"])
+            dt = time.perf_counter() - t0
         losses.append(loss)
         if profiling and step + 1 >= run.obs.profile_stop:
-            jax.profiler.stop_trace()
-            profiling = False
-            obs.emit("profile_window", start_step=run.obs.profile_start,
-                     stop_step=step + 1, trace_dir=prof_dir)
+            end_profile(step + 1)
         if step == start_step:
             steps_mod.check_state_placement(run, mesh, state)
         if monitor.observe(dt):
@@ -377,19 +395,19 @@ def main(argv=None):
                        "rank_map": rank_adapt.live_rank_map(state.params)}):
             if ckpt.preempted:
                 print(f"[preempt] checkpointed at step {step + 1}, exiting")
+                if profiling:  # the open trace window is saved, not lost
+                    end_profile(step + 1)
                 obs.emit("run_end", _mirror=False, kind="train",
                          reason="preempt", final_step=step + 1)
-                obs.close()
+                finish()
                 return state, losses
     if profiling:
-        jax.profiler.stop_trace()
-        obs.emit("profile_window", start_step=run.obs.profile_start,
-                 stop_step=args.steps, trace_dir=prof_dir)
+        end_profile(args.steps)
     print(f"done: loss {losses[0]:.4f} -> {losses[-1]:.4f}")
     obs.emit("run_end", _mirror=False, kind="train", reason="complete",
              final_step=args.steps,
              final_loss=losses[-1] if losses else 0.0)
-    obs.close()
+    finish()
     return state, losses
 
 

@@ -18,6 +18,7 @@ from repro.distributed import shard
 from repro.kernels import ops as kops
 from repro.models import common
 from repro.models.common import Params, apply_rope, linear, rmsnorm, rmsnorm_init
+from repro.obs import scope
 
 # --------------------------------------------------------------------------
 # Softmax attention cores
@@ -163,16 +164,20 @@ def blockwise_attention(q, k, v, *, causal: bool, block_q: int, block_kv: int) -
 def attention_core(q, k, v, cfg: ModelConfig, *, causal: bool,
                    interpret: bool = False) -> jax.Array:
     """``interpret`` runs the flash kernel in Pallas interpret mode (the
-    kernel policy's flag, for CPU validation)."""
-    if cfg.attention_impl == "flash":
-        out = _flash_path(q, k, v, cfg, causal=causal, interpret=interpret)
-        if out is not None:
-            return out
-    if cfg.attention_impl == "dense" or q.shape[1] <= cfg.attention_block_q:
-        return dense_attention(q, k, v, causal=causal)
-    return blockwise_attention(q, k, v, causal=causal,
-                               block_q=cfg.attention_block_q,
-                               block_kv=cfg.attention_block_kv)
+    kernel policy's flag, for CPU validation).  Scores, mask, softmax and
+    the weighted sum: the ``attn_core`` scope."""
+    with scope("attn_core"):
+        if cfg.attention_impl == "flash":
+            out = _flash_path(q, k, v, cfg, causal=causal,
+                              interpret=interpret)
+            if out is not None:
+                return out
+        if cfg.attention_impl == "dense" \
+                or q.shape[1] <= cfg.attention_block_q:
+            return dense_attention(q, k, v, causal=causal)
+        return blockwise_attention(q, k, v, causal=causal,
+                                   block_q=cfg.attention_block_q,
+                                   block_kv=cfg.attention_block_kv)
 
 
 def _flash_path(q, k, v, cfg: ModelConfig, *, causal: bool, interpret: bool):
@@ -225,9 +230,10 @@ def _update_rows(cache_leaf: jax.Array, new: jax.Array, rows) -> jax.Array:
     """Write one decode step (B, s, ...) into (B, Smax, ...) at per-row
     offsets (s = 1 plain decode, k+1 for the speculative verify chunk)."""
     zeros = (0,) * (cache_leaf.ndim - 2)
-    return jax.vmap(
-        lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p,) + zeros)
-    )(cache_leaf, new.astype(cache_leaf.dtype), rows)
+    with scope("kv_write"):
+        return jax.vmap(
+            lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p,) + zeros)
+        )(cache_leaf, new.astype(cache_leaf.dtype), rows)
 
 
 def _paged_write(pool: jax.Array, new: jax.Array, phys: jax.Array) -> jax.Array:
@@ -241,10 +247,11 @@ def _paged_write(pool: jax.Array, new: jax.Array, phys: jax.Array) -> jax.Array:
     there harmlessly.
     """
     nb, bs = pool.shape[0], pool.shape[1]
-    flat = pool.reshape((nb * bs,) + pool.shape[2:])
-    flat = flat.at[phys.reshape(-1)].set(
-        new.astype(pool.dtype).reshape((-1,) + pool.shape[2:]))
-    return flat.reshape(pool.shape)
+    with scope("kv_write"):
+        flat = pool.reshape((nb * bs,) + pool.shape[2:])
+        flat = flat.at[phys.reshape(-1)].set(
+            new.astype(pool.dtype).reshape((-1,) + pool.shape[2:]))
+        return flat.reshape(pool.shape)
 
 
 def _paged_gather(pool: jax.Array, page_table: jax.Array) -> jax.Array:
@@ -256,11 +263,12 @@ def _paged_gather(pool: jax.Array, page_table: jax.Array) -> jax.Array:
     masks them via ``kv_len`` exactly like contiguous-cache padding.
     """
     nb, bs = pool.shape[0], pool.shape[1]
-    flat = pool.reshape((nb * bs,) + pool.shape[2:])
-    b, mb = page_table.shape
-    phys = (page_table[:, :, None] * bs
-            + jnp.arange(bs, dtype=page_table.dtype)[None, None, :])
-    return flat[phys.reshape(b, mb * bs)]
+    with scope("kv_gather"):
+        flat = pool.reshape((nb * bs,) + pool.shape[2:])
+        b, mb = page_table.shape
+        phys = (page_table[:, :, None] * bs
+                + jnp.arange(bs, dtype=page_table.dtype)[None, None, :])
+        return flat[phys.reshape(b, mb * bs)]
 
 
 def _gqa_paged_update(cache: Params, k_new, v_new, rows,
@@ -286,8 +294,9 @@ def _gqa_paged_update(cache: Params, k_new, v_new, rows,
             + positions % bs)  # (B, S)
     if "k_scale" in cache:
         from repro.models import kvcache as kvq
-        kq, ks = kvq.quantize_kv(k_new)
-        vq, vs = kvq.quantize_kv(v_new)
+        with scope("kv_write"):
+            kq, ks = kvq.quantize_kv(k_new)
+            vq, vs = kvq.quantize_kv(v_new)
         new_cache = {
             "k": _paged_write(cache["k"], kq, phys),
             "v": _paged_write(cache["v"], vq, phys),
@@ -301,12 +310,13 @@ def _gqa_paged_update(cache: Params, k_new, v_new, rows,
             v_view = (_paged_gather(new_cache["v"], pt),
                       _paged_gather(new_cache["v_scale"], pt))
             return new_cache, k_view, v_view
-        k_view = kvq.dequantize_kv(_paged_gather(new_cache["k"], pt),
-                                   _paged_gather(new_cache["k_scale"], pt),
-                                   k_new.dtype)
-        v_view = kvq.dequantize_kv(_paged_gather(new_cache["v"], pt),
-                                   _paged_gather(new_cache["v_scale"], pt),
-                                   v_new.dtype)
+        with scope("kv_gather"):
+            k_view = kvq.dequantize_kv(
+                _paged_gather(new_cache["k"], pt),
+                _paged_gather(new_cache["k_scale"], pt), k_new.dtype)
+            v_view = kvq.dequantize_kv(
+                _paged_gather(new_cache["v"], pt),
+                _paged_gather(new_cache["v_scale"], pt), v_new.dtype)
     else:
         new_cache = {
             "k": _paged_write(cache["k"], k_new, phys),
@@ -344,6 +354,12 @@ def gqa_init(dec, key, path: str, cfg: ModelConfig, *, cross: bool = False,
 
 
 def _project_qkv(p, x, kv_src, cfg, rope, *, use_pallas=False):
+    """q, k, v projections with their norms and RoPE: ``attn_proj``."""
+    with scope("attn_proj"):
+        return _project_qkv_body(p, x, kv_src, cfg, rope, use_pallas)
+
+
+def _project_qkv_body(p, x, kv_src, cfg, rope, use_pallas):
     d, hd = cfg.d_model, cfg.resolved_head_dim
     h, kvh = cfg.num_heads, cfg.num_kv_heads
     b, s = x.shape[0], x.shape[1]
@@ -392,12 +408,15 @@ def gqa_apply(
         assert cache is not None and pos is not None
         if cross:
             # cross-attn kv computed at prefill; just read the cache
-            q = linear(p["wq"], x, use_pallas=use_pallas).reshape(b, s, h, hd)
-            if cfg.qk_norm:
-                q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
-            q = q * (hd ** -0.5)
+            with scope("attn_proj"):
+                q = linear(p["wq"], x,
+                           use_pallas=use_pallas).reshape(b, s, h, hd)
+                if cfg.qk_norm:
+                    q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
+                q = q * (hd ** -0.5)
             k, v = cache["k"], cache["v"]
-            out = dense_attention(q, k, v, causal=False)
+            with scope("attn_core"):
+                out = dense_attention(q, k, v, causal=False)
             new_cache = cache
         else:
             q, k_new, v_new = _project_qkv(p, x, None, cfg, rope, use_pallas=use_pallas)
@@ -416,8 +435,10 @@ def gqa_apply(
                     cache, k_new, v_new, rows, native_int8=native_int8)
             elif "k_scale" in cache:  # int8-quantized cache (§Perf C2)
                 from repro.models import kvcache as kvq
-                new_cache = kvq.update_quantized_kv(
-                    cache, k_new, v_new, rows if rows is not None else start)
+                with scope("kv_write"):
+                    new_cache = kvq.update_quantized_kv(
+                        cache, k_new, v_new,
+                        rows if rows is not None else start)
                 new_cache = {kk: shard(vv, "batch", "kv_seq", "kv_heads", None)
                              for kk, vv in new_cache.items()}
                 if native_int8:
@@ -433,26 +454,31 @@ def gqa_apply(
                     k_cache = _update_rows(cache["k"], k_new, rows)
                     v_cache = _update_rows(cache["v"], v_new, rows)
                 else:
-                    k_cache = jax.lax.dynamic_update_slice(
-                        cache["k"], k_new.astype(cache["k"].dtype), (0, start, 0, 0))
-                    v_cache = jax.lax.dynamic_update_slice(
-                        cache["v"], v_new.astype(cache["v"].dtype), (0, start, 0, 0))
+                    with scope("kv_write"):
+                        k_cache = jax.lax.dynamic_update_slice(
+                            cache["k"], k_new.astype(cache["k"].dtype),
+                            (0, start, 0, 0))
+                        v_cache = jax.lax.dynamic_update_slice(
+                            cache["v"], v_new.astype(cache["v"].dtype),
+                            (0, start, 0, 0))
                 k_cache = shard(k_cache, "batch", "kv_seq", "kv_heads", None)
                 v_cache = shard(v_cache, "batch", "kv_seq", "kv_heads", None)
                 new_cache = {"k": k_cache, "v": v_cache}
-            if isinstance(k_cache, tuple):  # native int8: raw pools + scales
-                out = int8_dense_attention(q, k_cache[0], k_cache[1],
-                                           v_cache[0], v_cache[1],
-                                           kv_len=length)
-            else:
-                out = dense_attention(q, k_cache, v_cache, causal=False,
-                                      kv_len=length)
+            with scope("attn_core"):
+                if isinstance(k_cache, tuple):  # native int8: pools + scales
+                    out = int8_dense_attention(q, k_cache[0], k_cache[1],
+                                               v_cache[0], v_cache[1],
+                                               kv_len=length)
+                else:
+                    out = dense_attention(q, k_cache, v_cache, causal=False,
+                                          kv_len=length)
 
-    out = out.reshape(b, s, h * hd)
-    out = shard(out, "batch", "seq", "heads")
-    y = linear(p["wo"], out, use_pallas=use_pallas)
-    if cross and "gate" in p:
-        y = y * jnp.tanh(p["gate"].astype(jnp.float32)).astype(y.dtype)
+    with scope("attn_proj"):
+        out = out.reshape(b, s, h * hd)
+        out = shard(out, "batch", "seq", "heads")
+        y = linear(p["wo"], out, use_pallas=use_pallas)
+        if cross and "gate" in p:
+            y = y * jnp.tanh(p["gate"].astype(jnp.float32)).astype(y.dtype)
     return y, new_cache
 
 
@@ -511,20 +537,23 @@ def mla_apply(
     lat = cfg.kv_lora_rank
     scale = (nd + rd) ** -0.5
 
-    ckv_full = linear(p["kv_down"], x, use_pallas=use_pallas)  # (B,S,lat+rd)
-    ckv = rmsnorm(p["kv_norm"], ckv_full[..., :lat], cfg.norm_eps)
-    k_rope = ckv_full[..., lat:].reshape(b, s, 1, rd)
-    if rope_k is not None:
-        cos, sin = rope_k
-        k_rope = apply_rope(k_rope, cos, sin)
-
-    q_nope, q_rope = _mla_q(p, x, cfg, rope_q, use_pallas)
+    with scope("attn_proj"):
+        ckv_full = linear(p["kv_down"], x, use_pallas=use_pallas)  # (B,S,lat+rd)
+        ckv = rmsnorm(p["kv_norm"], ckv_full[..., :lat], cfg.norm_eps)
+        k_rope = ckv_full[..., lat:].reshape(b, s, 1, rd)
+        if rope_k is not None:
+            cos, sin = rope_k
+            k_rope = apply_rope(k_rope, cos, sin)
+        q_nope, q_rope = _mla_q(p, x, cfg, rope_q, use_pallas)
 
     if mode == "full":
-        kv = linear(p["kv_up"], ckv, use_pallas=use_pallas).reshape(b, s, h, nd + vd)
-        k_nope, v = kv[..., :nd], kv[..., nd:]
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, h, rd))], axis=-1)
-        q = jnp.concatenate([q_nope, q_rope], axis=-1) * scale
+        with scope("attn_proj"):
+            kv = linear(p["kv_up"], ckv,
+                        use_pallas=use_pallas).reshape(b, s, h, nd + vd)
+            k_nope, v = kv[..., :nd], kv[..., nd:]
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (b, s, h, rd))], axis=-1)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1) * scale
         q = shard(q, "batch", "seq", "heads", None)
         k = shard(k, "batch", "kv_seq", "heads", None)
         v = shard(v, "batch", "kv_seq", "heads", None)
@@ -539,38 +568,53 @@ def mla_apply(
             ckv_cache = _update_rows(cache["ckv"], ckv, rows)
             kr_cache = _update_rows(cache["kr"], k_rope[:, :, 0, :], rows)
         else:
-            ckv_cache = jax.lax.dynamic_update_slice(
-                cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, start, 0))
-            kr_cache = jax.lax.dynamic_update_slice(
-                cache["kr"], k_rope[:, :, 0, :].astype(cache["kr"].dtype), (0, start, 0))
+            with scope("kv_write"):
+                ckv_cache = jax.lax.dynamic_update_slice(
+                    cache["ckv"], ckv.astype(cache["ckv"].dtype),
+                    (0, start, 0))
+                kr_cache = jax.lax.dynamic_update_slice(
+                    cache["kr"], k_rope[:, :, 0, :].astype(cache["kr"].dtype),
+                    (0, start, 0))
         ckv_cache = shard(ckv_cache, "batch", "kv_seq", None)
-        w_kv = p["kv_up"]["kernel"] if "kernel" in p["kv_up"] else (
-            jnp.dot(p["kv_up"]["u"], p["kv_up"]["v"]))
-        w_kv = w_kv.reshape(lat, h, nd + vd)
-        w_uk, w_uv = w_kv[..., :nd], w_kv[..., nd:]
-        # latent cache stays bf16; fp32 only through accumulation (§Perf C1)
-        q_lat = jnp.einsum("bshn,lhn->bshl", q_nope, w_uk,
-                           preferred_element_type=jnp.float32).astype(x.dtype)
-        logits = (
-            jnp.einsum("bshl,btl->bhst", q_lat, ckv_cache,
-                       preferred_element_type=jnp.float32)
-            + jnp.einsum("bshr,btr->bhst", q_rope, kr_cache,
-                         preferred_element_type=jnp.float32)
-        ) * scale
-        base = rows if rows is not None else jnp.broadcast_to(start, (b,))
-        # (B, S, T) mask: per-row live length, advancing per chunk position
-        # (in-chunk causality for the speculative verify step, S > 1).
-        length = (base[:, None].astype(jnp.int32) + 1
-                  + jnp.arange(s, dtype=jnp.int32)[None, :])
-        valid = (jnp.arange(logits.shape[-1])[None, None, :]
-                 < length[:, :, None])
-        logits = jnp.where(valid[:, None, :, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        ctx_lat = jnp.einsum("bhst,btl->bshl", probs.astype(x.dtype), ckv_cache,
-                             preferred_element_type=jnp.float32).astype(x.dtype)
-        out = jnp.einsum("bshl,lhv->bshv", ctx_lat, w_uv,
-                         preferred_element_type=jnp.float32).astype(x.dtype)
+        with scope("attn_core"):
+            out = _mla_absorbed(p, q_nope, q_rope, ckv_cache, kr_cache, x,
+                                rows, start, cfg, scale)
         new_cache = {"ckv": ckv_cache, "kr": kr_cache}
 
-    y = linear(p["wo"], out.reshape(b, s, h * vd), use_pallas=use_pallas)
+    with scope("attn_proj"):
+        y = linear(p["wo"], out.reshape(b, s, h * vd), use_pallas=use_pallas)
     return y, new_cache
+
+
+def _mla_absorbed(p, q_nope, q_rope, ckv_cache, kr_cache, x, rows, start,
+                  cfg: ModelConfig, scale):
+    """MLA's absorbed decode attention: scores in the latent space."""
+    b, s, h = q_nope.shape[0], q_nope.shape[1], cfg.num_heads
+    nd, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    lat = cfg.kv_lora_rank
+    w_kv = p["kv_up"]["kernel"] if "kernel" in p["kv_up"] else (
+        jnp.dot(p["kv_up"]["u"], p["kv_up"]["v"]))
+    w_kv = w_kv.reshape(lat, h, nd + vd)
+    w_uk, w_uv = w_kv[..., :nd], w_kv[..., nd:]
+    # latent cache stays bf16; fp32 only through accumulation (§Perf C1)
+    q_lat = jnp.einsum("bshn,lhn->bshl", q_nope, w_uk,
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    logits = (
+        jnp.einsum("bshl,btl->bhst", q_lat, ckv_cache,
+                   preferred_element_type=jnp.float32)
+        + jnp.einsum("bshr,btr->bhst", q_rope, kr_cache,
+                     preferred_element_type=jnp.float32)
+    ) * scale
+    base = rows if rows is not None else jnp.broadcast_to(start, (b,))
+    # (B, S, T) mask: per-row live length, advancing per chunk position
+    # (in-chunk causality for the speculative verify step, S > 1).
+    length = (base[:, None].astype(jnp.int32) + 1
+              + jnp.arange(s, dtype=jnp.int32)[None, :])
+    valid = (jnp.arange(logits.shape[-1])[None, None, :]
+             < length[:, :, None])
+    logits = jnp.where(valid[:, None, :, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    ctx_lat = jnp.einsum("bhst,btl->bshl", probs.astype(x.dtype), ckv_cache,
+                         preferred_element_type=jnp.float32).astype(x.dtype)
+    return jnp.einsum("bshl,lhv->bshv", ctx_lat, w_uv,
+                      preferred_element_type=jnp.float32).astype(x.dtype)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
+from repro.obs import scope
 
 Params = Dict[str, Any]
 
@@ -199,6 +200,11 @@ def ffn_init(dec, key, path: str, d: int, f: int, activation: str, dtype,
 
 def ffn(p: Params, x: jax.Array, *,
         use_pallas: "bool | kops.KernelPolicy" = False) -> jax.Array:
+    with scope("ffn"):
+        return _ffn(p, x, use_pallas)
+
+
+def _ffn(p: Params, x: jax.Array, use_pallas) -> jax.Array:
     from repro.distributed import shard  # local import to avoid cycles
 
     if "gate" in p:

@@ -28,6 +28,7 @@ from repro.models.attention import gqa_apply, gqa_init, mla_apply, mla_init
 from repro.models.common import (Params, cross_entropy, embed, embedding_init,
                                  ffn, ffn_init, linear, mask_vocab, rmsnorm,
                                  rmsnorm_init, rope_table)
+from repro.obs import scope
 
 
 def _bc(p: Params, stack: Tuple[int, ...]) -> Params:
@@ -64,7 +65,8 @@ def decoder_layer_apply(lp: Params, h: jax.Array, cfg: ModelConfig, *, rope,
                         kv_src: Optional[jax.Array] = None):
     # "train" == "full" without materializing KV caches through scan ys.
     attn_mode = "full" if mode == "train" else mode
-    a_in = rmsnorm(lp["norm1"], h, cfg.norm_eps)
+    with scope("norm"):
+        a_in = rmsnorm(lp["norm1"], h, cfg.norm_eps)
     if cfg.use_mla:
         a_out, new_cache = mla_apply(lp["attn"], a_in, cfg, rope_q=rope, rope_k=rope,
                                      mode=attn_mode, cache=cache, pos=pos,
@@ -77,9 +79,12 @@ def decoder_layer_apply(lp: Params, h: jax.Array, cfg: ModelConfig, *, rope,
     if mode == "train":
         new_cache = None
     h = h + a_out
-    f_in = rmsnorm(lp["norm2"], h, cfg.norm_eps)
+    with scope("norm"):
+        f_in = rmsnorm(lp["norm2"], h, cfg.norm_eps)
     if moe_layer:
-        f_out, aux = moe_mod.moe_apply(lp["moe"], f_in, cfg, use_pallas=use_pallas)
+        with scope("ffn"):
+            f_out, aux = moe_mod.moe_apply(lp["moe"], f_in, cfg,
+                                           use_pallas=use_pallas)
     else:
         f_out, aux = ffn(lp["ffn"], f_in, use_pallas=use_pallas), jnp.zeros((), jnp.float32)
     h = h + f_out
@@ -271,45 +276,56 @@ def lm_apply(
     """
     b, s = tokens.shape
     hd = cfg.resolved_head_dim
-    h = embed(p["embed"], tokens).astype(cfg.cdtype)
-    h = shard(h, "batch", "seq", "embed")
+    with scope("embed"):
+        h = embed(p["embed"], tokens).astype(cfg.cdtype)
+        h = shard(h, "batch", "seq", "embed")
 
-    rope = _make_rope(cfg, s, "full" if mode == "train" else mode, pos)
+    with scope("attn_proj"):  # the RoPE tables the projections apply
+        rope = _make_rope(cfg, s, "full" if mode == "train" else mode, pos)
     fam = cfg.family
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
 
-    if fam in ("dense", "moe"):
-        for name, moe_layer in (("stack", False), ("dense_stack", False), ("moe_stack", True)):
-            if name not in p:
-                continue
-            body = functools.partial(
-                _decoder_body, cfg=cfg, rope=rope, mode=mode, pos=pos,
-                moe_layer=moe_layer, use_pallas=use_pallas)
-            h, nc, aux = _scan_stack(p[name], h, body,
-                                     cache.get(name) if cache else None, remat)
-            new_cache[name] = nc
-            aux_total += aux
-    elif fam == "vlm":
-        h, new_cache, aux_total = _vlm_forward(p, h, cfg, rope, mode, cache, pos,
-                                               vision_embeddings, remat, use_pallas)
-    elif fam == "hybrid":
-        h, new_cache, aux_total = _hybrid_forward(p, h, cfg, rope, mode, cache, pos,
-                                                  remat, use_pallas)
-    elif fam == "ssm":
-        body = functools.partial(_mlstm_body, cfg=cfg, mode=mode, use_pallas=use_pallas)
-        h, nc, aux_total = _scan_stack(p["stack"], h, body,
-                                       cache.get("stack") if cache else None, remat)
-        new_cache["stack"] = nc
+    # the layer scans, their stacking and their carries
+    with scope("layers"):
+        if fam in ("dense", "moe"):
+            for name, moe_layer in (("stack", False), ("dense_stack", False),
+                                    ("moe_stack", True)):
+                if name not in p:
+                    continue
+                body = functools.partial(
+                    _decoder_body, cfg=cfg, rope=rope, mode=mode, pos=pos,
+                    moe_layer=moe_layer, use_pallas=use_pallas)
+                h, nc, aux = _scan_stack(p[name], h, body,
+                                         cache.get(name) if cache else None,
+                                         remat)
+                new_cache[name] = nc
+                aux_total += aux
+        elif fam == "vlm":
+            h, new_cache, aux_total = _vlm_forward(
+                p, h, cfg, rope, mode, cache, pos, vision_embeddings, remat,
+                use_pallas)
+        elif fam == "hybrid":
+            h, new_cache, aux_total = _hybrid_forward(
+                p, h, cfg, rope, mode, cache, pos, remat, use_pallas)
+        elif fam == "ssm":
+            body = functools.partial(_mlstm_body, cfg=cfg, mode=mode,
+                                     use_pallas=use_pallas)
+            h, nc, aux_total = _scan_stack(
+                p["stack"], h, body, cache.get("stack") if cache else None,
+                remat)
+            new_cache["stack"] = nc
 
-    h = rmsnorm(p["final_norm"], h, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.dot(h, p["embed"]["embedding"].T,
-                         preferred_element_type=jnp.float32)
-    else:
-        logits = linear(p["unembed"], h, use_pallas=use_pallas).astype(jnp.float32)
-    logits = mask_vocab(logits, cfg.vocab_size)
-    logits = shard(logits, "batch", "seq", "vocab")
+    with scope("head"):
+        h = rmsnorm(p["final_norm"], h, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = jnp.dot(h, p["embed"]["embedding"].T,
+                             preferred_element_type=jnp.float32)
+        else:
+            logits = linear(p["unembed"], h,
+                            use_pallas=use_pallas).astype(jnp.float32)
+        logits = mask_vocab(logits, cfg.vocab_size)
+        logits = shard(logits, "batch", "seq", "vocab")
     if return_hidden:
         return logits, new_cache, aux_total, h
     return logits, new_cache, aux_total

@@ -22,6 +22,7 @@ import time
 from typing import Callable, Optional
 
 from repro.obs.schema import SCHEMA_VERSION, validate_event
+from repro.obs.spans import span
 
 
 def render_text(ev: dict) -> Optional[str]:
@@ -117,16 +118,18 @@ class EventLog:
     def span(self, etype: str, _mirror: bool = True, **fields):
         """Context manager emitting ``etype`` with a ``dur_s`` field on
         exit.  Yields a dict; keys added to it land on the event — use it
-        to attach results computed inside the span."""
-        t0 = time.perf_counter()
+        to attach results computed inside the span.  The span is also a
+        host span called ``etype`` on the profiler's trace
+        (:func:`repro.obs.span`), whose length is the event's ``dur_s``."""
         extra: dict = {}
+        s = span(etype, **fields)
         try:
-            yield extra
+            with s:
+                yield extra
         finally:
             merged = dict(fields)
             merged.update(extra)
-            self.emit(etype, _mirror=_mirror,
-                      dur_s=time.perf_counter() - t0, **merged)
+            self.emit(etype, _mirror=_mirror, dur_s=s.dur_s, **merged)
 
     def close(self) -> None:
         if self._f is not None:

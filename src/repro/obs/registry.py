@@ -36,7 +36,10 @@ class _Metric:
         self.name = name
         self.help = help
         self._series: Dict[LabelKey, object] = {}
-        self._lock = threading.Lock()
+        # reentrant: the garbage-collection hook (repro.obs.spans) counts
+        # from inside a collection that any allocation under the lock
+        # can start, on the same thread
+        self._lock = threading.RLock()
 
     def _get(self, labels: Dict[str, object], default):
         key = _label_key(labels)
@@ -142,7 +145,7 @@ class MetricsRegistry:
 
     def __init__(self):
         self._metrics: Dict[str, _Metric] = {}
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()  # reentrant, as _Metric's
 
     def _get_or_create(self, kind: str, name: str, help: str) -> _Metric:
         with self._lock:

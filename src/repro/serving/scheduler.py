@@ -55,7 +55,7 @@ from repro.configs.base import RunConfig
 from repro.distributed import (FROZEN_PARAM_RULES, named_shardings,
                                paged_pool_specs)
 from repro.launch import steps as steps_mod
-from repro.obs import NULL_LOG, EventLog, default_registry
+from repro.obs import NULL_LOG, EventLog, span
 from repro.serving import paged_cache as pc
 from repro.serving import speculative
 from repro.serving.radix_cache import RadixCache
@@ -494,6 +494,14 @@ class Scheduler:
 
     def _start(self, idx: int, slot: _Slot, req: Request,
                fed: np.ndarray, shared: Optional[List[int]] = None) -> None:
+        """Prefill (or extend past a radix hit) one admitted request into
+        slot ``idx``, insert its KV and read back its first token: one
+        ``sched.prefill`` span on the trace."""
+        with span("sched.prefill", rid=req.rid, fed_len=int(fed.size)):
+            self._prefill_into(idx, slot, req, fed, shared)
+
+    def _prefill_into(self, idx: int, slot: _Slot, req: Request,
+                      fed: np.ndarray, shared: Optional[List[int]]) -> None:
         now = self._now()
         resume = bool(req.tokens)
         hit = len(shared or []) * (self.block_size if self.pages else 0)
@@ -600,35 +608,38 @@ class Scheduler:
         # two dispatches per step regardless of k: the fused draft chain
         # (all k tokens inside one program) then the chunked verify — the
         # only host syncs are the two reads after verify
-        self.cache, chunk_dev = self._draft(
-            self.draft_params, self.cache, jnp.asarray(self._tokens),
-            jnp.asarray(self._positions))
-        self.cache, verify = self._verify(
-            self.params, self.cache, chunk_dev,
-            jnp.asarray(self._positions))
-        chunk = np.asarray(chunk_dev)
-        verify = np.asarray(verify)
-        verify = np.asarray(verify)
+        with span("sched.draft"):
+            self.cache, chunk_dev = self._draft(
+                self.draft_params, self.cache, jnp.asarray(self._tokens),
+                jnp.asarray(self._positions))
+        with span("sched.verify"):
+            self.cache, verify = self._verify(
+                self.params, self.cache, chunk_dev,
+                jnp.asarray(self._positions))
+        with span("sched.readback"):
+            chunk = np.asarray(chunk_dev)
+            verify = np.asarray(verify)
         ns = speculative.accept_lengths(chunk, verify)
         drafted = accepted = emitted = 0
-        for i, s in active:
-            if not s.active:
-                continue
-            acc = int(ns[i])
-            drafted += k
-            accepted += acc
-            s.req.drafted += k
-            s.req.accepted += acc
-            for j in range(acc):
-                s.pos += 1
-                self._emit(s, int(chunk[i, j + 1]))
-                emitted += 1
-                if not s.active:  # retired mid-chunk (eos / max_new)
-                    break
-            if s.active:
-                s.pos += 1
-                self._emit(s, int(verify[i, acc]))
-                emitted += 1
+        with span("sched.emit"):
+            for i, s in active:
+                if not s.active:
+                    continue
+                acc = int(ns[i])
+                drafted += k
+                accepted += acc
+                s.req.drafted += k
+                s.req.accepted += acc
+                for j in range(acc):
+                    s.pos += 1
+                    self._emit(s, int(chunk[i, j + 1]))
+                    emitted += 1
+                    if not s.active:  # retired mid-chunk (eos / max_new)
+                        break
+                if s.active:
+                    s.pos += 1
+                    self._emit(s, int(verify[i, acc]))
+                    emitted += 1
         st = self.spec_stats
         st["spec_steps"] += 1
         st["drafted"] += drafted
@@ -648,19 +659,26 @@ class Scheduler:
             rate = accepted / drafted if drafted else 0.0
             self.obs.emit("spec_step", drafted=drafted, accepted=accepted,
                           emitted=emitted, acceptance_rate=rate)
-            reg = default_registry()
-            reg.counter("spec_drafted_tokens").inc(drafted)
-            reg.counter("spec_accepted_tokens").inc(accepted)
-            reg.counter("spec_rejected_tokens").inc(drafted - accepted)
-            reg.gauge("spec_acceptance_rate").set(self.acceptance_rate())
 
     # -- the step ----------------------------------------------------------
 
     def step(self) -> None:
-        """Admit what fits, then run one fixed-shape decode step."""
+        """Admit what fits, then run one fixed-shape decode step.
+
+        On the trace: ``sched.step`` around it all; inside, ``sched.admit``
+        (admission with its ``sched.prefill`` spans, and page growth),
+        ``sched.page_table`` (the table upload), ``sched.decode`` (the
+        dispatch, or ``sched.draft`` + ``sched.verify``),
+        ``sched.readback`` (the blocking read of the tokens) and
+        ``sched.emit`` (the per-token bookkeeping)."""
+        with span("sched.step"):
+            self._step()
+
+    def _step(self) -> None:
         now = self._now()
-        self._admit(now)
-        self._ensure_pages()
+        with span("sched.admit"):
+            self._admit(now)
+            self._ensure_pages()
         active = [(i, s) for i, s in enumerate(self.slots) if s.active]
         if not active:
             return
@@ -672,22 +690,26 @@ class Scheduler:
             # admit/grow/release skip the host->device table upload; the
             # upload uses the step's own output sharding so the executable
             # signature never flips between uploaded and echoed tables
-            self.cache = pc.with_page_table(
-                self.cache, self.pages.table,
-                sharding=NamedSharding(self.mesh, PartitionSpec()))
+            with span("sched.page_table"):
+                self.cache = pc.with_page_table(
+                    self.cache, self.pages.table,
+                    sharding=NamedSharding(self.mesh, PartitionSpec()))
             self._pt_version = self.pages.version
         if self.spec_k:
             self._spec_decode(active)
         else:
-            _, self.cache, nxt = self._decode(
-                self.params, self.cache, jnp.asarray(self._tokens),
-                jnp.asarray(self._positions), None)
-            nxt = np.asarray(nxt)
-            for i, s in active:
-                if not s.active:  # preempted between bookkeeping passes
-                    continue
-                s.pos += 1
-                self._emit(s, int(nxt[i, 0]))
+            with span("sched.decode"):
+                _, self.cache, nxt = self._decode(
+                    self.params, self.cache, jnp.asarray(self._tokens),
+                    jnp.asarray(self._positions), None)
+            with span("sched.readback"):
+                nxt = np.asarray(nxt)
+            with span("sched.emit"):
+                for i, s in active:
+                    if not s.active:  # preempted between bookkeeping passes
+                        continue
+                    s.pos += 1
+                    self._emit(s, int(nxt[i, 0]))
         if self.obs.active:
             if self.spec_k:
                 self._note_compiles("draft")
@@ -701,11 +723,6 @@ class Scheduler:
                           pool_free=self.pages.allocator.free_blocks,
                           pool_high_water=self.pages.high_water)
             self.obs.emit("serve_step", **ev)
-            reg = default_registry()
-            reg.gauge("serve_active_slots").set(ev["active_slots"])
-            if self.pages is not None:
-                reg.gauge("serve_pool_used_blocks").set(ev["pool_used"])
-                reg.gauge("serve_pool_high_water").set(ev["pool_high_water"])
 
     def run(self, poll: float = 0.0005) -> Dict[int, np.ndarray]:
         """Drive until queue and slots drain; returns rid -> tokens."""
@@ -741,8 +758,8 @@ class Scheduler:
         raises otherwise, because in-flight requests hold timestamps on
         the old clock); the next ``_now()`` re-anchors virtual time at
         zero, so arrival offsets of a subsequently submitted trace are
-        relative to that moment.  Compile caches, the page pool, and the
-        metrics-registry series survive — only per-request records reset.
+        relative to that moment.  Compile caches and the page pool
+        survive — only per-request records reset.
         Call it between a compile-warmup run and a measured trace replay.
         """
         if self.has_work():
