@@ -332,15 +332,17 @@ def place_at_paths(tree: Any, mesh: Mesh, rules: RuleTable,
     return walk(tree, specs, "")
 
 
-def paged_pool_specs(cache: Any, mesh: Mesh) -> Any:
+def paged_pool_specs(cache: Any, mesh: Mesh, kv_heads: int) -> Any:
     """PartitionSpec pytree for a paged serving cache (DESIGN.md §14).
 
-    Pool leaves (L, num_blocks, block_size, KV, hd) — and their int8 scale
-    siblings (..., KV, 1) — shard the KV-head dim over ``model`` when it
-    divides; page tables and anything else stay replicated.  The serving
-    step builders clamp their cache *outputs* with exactly these specs so
-    the executable's output placement matches the init/upload placement and
-    the compile-once contract holds on a multi-device mesh.
+    Pool leaves (L, num_blocks, block_size, KV * 2 * hd) — and the int8
+    pool's scale sibling (..., KV * 2) — shard their last dim over
+    ``model`` when ``kv_heads`` divides: each KV head's lanes are
+    contiguous (serving/paged_cache.py), so a device holds whole heads.
+    Page tables and anything else stay replicated.  The scheduler pins
+    its programs' cache *outputs* to exactly these specs
+    (``out_shardings``) so the executable's output placement matches the
+    init/upload placement and the compile-once contract holds.
 
     Mesh axes of size 1 are pruned from the resolved specs: naming them is
     semantically replication, but GSPMD normalizes jit *output* shardings
@@ -365,9 +367,10 @@ def paged_pool_specs(cache: Any, mesh: Mesh) -> Any:
     def walk(tree, name):
         if isinstance(tree, dict):
             return {k: walk(v, k) for k, v in tree.items()}
-        if name in ("k", "v", "k_scale", "v_scale") and tree.ndim == 5:
-            axes = (None, None, None, "kv_heads", None)
-            return prune(_resolve_spec(tree.shape, axes, ACT_RULES, mesh))
+        if name in ("kv", "kv_scale"):
+            axes = (None, None, None, "kv_heads")
+            return prune(_resolve_spec(tree.shape[:-1] + (kv_heads,), axes,
+                                       ACT_RULES, mesh))
         return P()
 
     return walk(cache, "")

@@ -108,7 +108,7 @@ def capture_fallbacks():
 
 # reasons that are expected on the current host/policy — everything else
 # means a kernel the caller explicitly requested quietly degraded
-_EXPECTED_FALLBACK_REASONS = ("platform", "disabled")
+_EXPECTED_FALLBACK_REASONS = ("platform", "disabled", "int8_pool", "chunk")
 
 
 def _note_fallback(op: str, reason: str, shape: Tuple[int, ...] = ()) -> None:
@@ -770,3 +770,26 @@ def int8_lowrank_apply(x: jax.Array, u_q: jax.Array, u_scale: jax.Array,
     t = jnp.dot(xf, u_q.astype(jnp.float32) * us)
     y = jnp.dot(t, v_q.astype(jnp.float32) * vs)
     return y.astype(x.dtype).reshape(*lead, s)
+
+
+# --------------------------------------------------------------------------
+# paged decode attention
+# --------------------------------------------------------------------------
+
+def paged_attention_kernel(*, int8_pool: bool, chunk: int, interpret: bool,
+                           shape: Tuple[int, ...]) -> bool:
+    """Whether a paged decode step attends through the paged-attention
+    kernel (``kernels/paged_attention.py``), chosen by what the trace
+    shows: a bf16 pool, one token per slot (``chunk`` == 1), a TPU or
+    interpret mode, one device.  Otherwise notes why and returns False:
+    the caller gathers the slots' views and runs dense attention.
+
+    ``int8_pool`` and ``chunk`` (the speculative verify and radix extend
+    chunks) are the gather path by design; ``mesh``: under a multi-device
+    mesh the partitioner would replicate the bare kernel, pool and all."""
+    reason = ("int8_pool" if int8_pool else "chunk" if chunk > 1
+              else "platform" if not (kernel_available() or interpret)
+              else "mesh" if _multi_device_mesh() else None)
+    if reason is not None:
+        _note_fallback("paged_attention", reason, shape)
+    return reason is None

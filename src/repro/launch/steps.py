@@ -45,7 +45,7 @@ from repro.core.decompose import Decomposer
 from repro.core.policy import LM_DEFAULT, NO_LRD
 from repro.distributed import (ACT_RULES, ACT_RULES_SP, FROZEN_PARAM_RULES,
                                PARAM_RULES, PARAM_RULES_NO_FSDP, axis_rules,
-                               named_shardings, paged_pool_specs, param_specs,
+                               named_shardings, param_specs,
                                place_at_paths, shard)
 from repro.distributed.compression import value_and_grad_compressed
 from repro.kernels.ops import KernelPolicy
@@ -613,31 +613,6 @@ def build_slot_prefill_step(run: RunConfig, mesh):
     return slot_prefill_step
 
 
-def clamp_paged_cache(cache, mesh):
-    """Pin a paged cache's output placement to its init placement.
-
-    On a multi-device mesh GSPMD is free to pick different output shardings
-    for the echoed cache than the inputs carried, which would change the
-    executable signature the next step sees and break the compile-once
-    contract.  Every serving step that returns a paged cache (decode /
-    draft / verify / insert / extend) runs its result through this clamp so
-    the pool leaves stay KV-head-sharded over ``model`` (page tables
-    replicated) exactly as :func:`repro.distributed.paged_pool_specs` — and
-    the scheduler — placed them.  No-op on 1-device meshes and non-paged
-    (contiguous slot) caches.
-    """
-    if mesh.devices.size == 1:
-        return cache
-    if not any(isinstance(s, dict) and "page_table" in s
-               for s in cache.values()):
-        return cache
-    specs = paged_pool_specs(cache, mesh)
-    return jax.tree_util.tree_map(
-        lambda x, sp: jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, sp)),
-        cache, specs)
-
-
 def build_serve_step(run: RunConfig, mesh):
     """One decode step for the whole engine lifetime.
 
@@ -663,7 +638,7 @@ def build_serve_step(run: RunConfig, mesh):
                     params, token, cfg, mode="decode", cache=cache, pos=pos,
                     vision_embeddings=(extras or {}).get("vision_embeddings"), **kw)
             next_token = jnp.argmax(logits[:, -1:], axis=-1).astype(token.dtype)
-            return logits, clamp_paged_cache(new_cache, mesh), next_token
+            return logits, new_cache, next_token
 
     return serve_step
 
@@ -692,8 +667,7 @@ def build_draft_chain(run: RunConfig, mesh, k: int):
                     pos=pos + j, **kw)
                 toks.append(jnp.argmax(logits[:, -1:], axis=-1)
                             .astype(token.dtype))
-            return (clamp_paged_cache(cache, mesh),
-                    jnp.concatenate(toks, axis=1))
+            return cache, jnp.concatenate(toks, axis=1)
 
     return draft_chain
 
@@ -718,7 +692,7 @@ def build_verify_step(run: RunConfig, mesh):
                 params, tokens, cfg, mode="decode", cache=cache, pos=pos,
                 use_pallas=kernel_policy(run))
             next_tokens = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
-            return clamp_paged_cache(new_cache, mesh), next_tokens
+            return new_cache, next_tokens
 
     return verify_step
 
@@ -745,12 +719,9 @@ def build_extend_step(run: RunConfig, mesh):
     def extend_step(params, cache, tokens, page_row, start):
         act = ACT_RULES_SP if run.dist.sequence_parallel else ACT_RULES
         with axis_rules(mesh, act=act, params=_param_rules(run)):
-            view = {}
-            for name, stack in cache.items():
-                row = page_row.astype(jnp.int32).reshape(1, -1)
-                view[name] = dict(
-                    stack, page_table=jnp.broadcast_to(
-                        row, (stack["page_table"].shape[0],) + row.shape))
+            row = page_row.astype(jnp.int32).reshape(1, -1)
+            view = {name: dict(stack, page_table=row)
+                    for name, stack in cache.items()}
             pos = jnp.asarray(start, jnp.int32).reshape(1)
             logits, new_view, _ = lm.lm_apply(
                 params, tokens, cfg, mode="decode", cache=view, pos=pos,
@@ -758,7 +729,7 @@ def build_extend_step(run: RunConfig, mesh):
             out = {name: dict(stack, page_table=cache[name]["page_table"])
                    for name, stack in new_view.items()}
             next_tokens = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
-            return clamp_paged_cache(out, mesh), next_tokens
+            return out, next_tokens
 
     return extend_step
 

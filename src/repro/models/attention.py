@@ -236,96 +236,140 @@ def _update_rows(cache_leaf: jax.Array, new: jax.Array, rows) -> jax.Array:
         )(cache_leaf, new.astype(cache_leaf.dtype), rows)
 
 
-def _paged_write(pool: jax.Array, new: jax.Array, phys: jax.Array) -> jax.Array:
-    """Scatter one decode step into the block pool.
+def _paged_write(pool: jax.Array, layer, rows: jax.Array, blk: jax.Array,
+                 off: jax.Array) -> jax.Array:
+    """Write one decode step's rows into layer ``layer`` of the stacked
+    pool, in place: the pool rides in the layer loop's carry and only the
+    written rows move.
 
-    pool: (num_blocks, block_size, ...); new: (B, S, ...); phys: (B, S) flat
-    physical positions (block_id * block_size + offset).  S is 1 for plain
-    decode and k+1 for the speculative verify chunk.  Distinct slots own
-    distinct blocks, so indices never collide; retired slots point at the
-    reserved sink block 0 (serving/paged_cache.py) and their writes land
-    there harmlessly.
+    pool: (L, num_blocks, block_size, W); rows: (B, S, W); blk/off: (B, S)
+    physical block and offset of each row.  S is 1 for plain decode and k+1
+    for the speculative verify chunk.  Distinct slots own distinct blocks,
+    so indices never collide; retired slots point at the reserved sink
+    block 0 (serving/paged_cache.py) and their writes land there
+    harmlessly.
     """
-    nb, bs = pool.shape[0], pool.shape[1]
     with scope("kv_write"):
-        flat = pool.reshape((nb * bs,) + pool.shape[2:])
-        flat = flat.at[phys.reshape(-1)].set(
-            new.astype(pool.dtype).reshape((-1,) + pool.shape[2:]))
-        return flat.reshape(pool.shape)
+        return pool.at[layer, blk.reshape(-1), off.reshape(-1)].set(
+            rows.reshape(-1, pool.shape[-1]).astype(pool.dtype))
 
 
-def _paged_gather(pool: jax.Array, page_table: jax.Array) -> jax.Array:
-    """Gather every slot's logical view from the block pool.
+def _paged_gather(pool: jax.Array, layer, page_table: jax.Array) -> jax.Array:
+    """Gather every slot's logical view of layer ``layer`` from the pool.
 
-    pool: (num_blocks, block_size, ...); page_table: (B, max_blocks) int32
-    -> (B, max_blocks * block_size, ...).  Positions beyond a slot's length
-    read whatever sits in its tail blocks (or the sink block); attention
-    masks them via ``kv_len`` exactly like contiguous-cache padding.
+    pool: (L, num_blocks, block_size, W); page_table: (B, max_blocks)
+    int32 -> (B, max_blocks * block_size, W).  Positions beyond a slot's
+    length read whatever sits in its tail blocks (or the sink block);
+    attention masks them via ``kv_len`` exactly like contiguous-cache
+    padding.  The gather path: int8 pools and multi-token chunks.
     """
-    nb, bs = pool.shape[0], pool.shape[1]
     with scope("kv_gather"):
-        flat = pool.reshape((nb * bs,) + pool.shape[2:])
         b, mb = page_table.shape
-        phys = (page_table[:, :, None] * bs
-                + jnp.arange(bs, dtype=page_table.dtype)[None, None, :])
-        return flat[phys.reshape(b, mb * bs)]
+        return pool[layer, page_table].reshape(b, mb * pool.shape[2],
+                                               pool.shape[3])
 
 
-def _gqa_paged_update(cache: Params, k_new, v_new, rows,
-                      *, native_int8: bool = False,
-                      ) -> Tuple[Params, Any, Any]:
-    """Write this step's k/v into the paged pool and gather per-slot views.
+def _gqa_paged_decode(cache: Params, q, k_new, v_new, rows, length, *,
+                      native_int8: bool, interpret: bool,
+                      ) -> Tuple[jax.Array, Params]:
+    """Write this step's k/v into the paged pool and attend over it.
 
-    cache: {"k","v"[, "k_scale","v_scale"], "page_table"} with pools shaped
-    (num_blocks, block_size, KV, hd) and page_table (B, max_blocks).
-    ``k_new``/``v_new`` are (B, S, KV, hd) with S >= 1: row b writes at
-    logical positions rows[b] .. rows[b]+S-1 (the speculative verify chunk
-    writes k+1 positions in one step).  Returns (new_cache, k_view, v_view)
-    where the views are (B, Lmax, KV, *) logical per-slot caches.  int8
-    pools: ``native_int8=True`` returns the raw ``(values, scales)`` pairs
-    for :func:`int8_dense_attention`; otherwise the views are dequantized
-    (legacy bf16 round trip).
+    cache: {"kv"[, "kv_scale"]: the whole stacked pool (serving/
+    paged_cache.py), "layer": this layer's index, "page_table": (B,
+    max_blocks)}.  ``k_new``/``v_new`` are (B, S, KV, hd) with S >= 1: row
+    b writes at logical positions rows[b] .. rows[b]+S-1.  A bf16 pool with
+    S == 1 attends through the paged-attention kernel, which reads only
+    each slot's live blocks where they lie; int8 pools and chunks gather
+    per-slot views for dense attention (``native_int8``: straight on the
+    int8 values and scales, else dequantized).  Returns (out, new_cache)
+    with the pool leaves updated.
     """
-    pt = cache["page_table"]
-    bs = cache["k"].shape[1]
-    s = k_new.shape[1]
+    from repro.models import kvcache as kvq
+    pt, layer = cache["page_table"], cache["layer"]
+    b, s, heads = k_new.shape[0], k_new.shape[1], k_new.shape[2]
+    bs = cache["kv"].shape[2]
     positions = rows[:, None] + jnp.arange(s, dtype=rows.dtype)  # (B, S)
-    phys = (pt[jnp.arange(pt.shape[0])[:, None], positions // bs] * bs
-            + positions % bs)  # (B, S)
-    if "k_scale" in cache:
+    blk = pt[jnp.arange(b)[:, None], positions // bs]
+    off = positions % bs
+    new_rows = kvq.fuse_kv(k_new, v_new)
+    new_cache = dict(cache)
+    int8_pool = "kv_scale" in cache
+    if int8_pool:
+        with scope("kv_write"):
+            new_rows, scales = kvq.quantize_rows(new_rows, heads)
+        new_cache["kv_scale"] = _paged_write(cache["kv_scale"], layer,
+                                             scales, blk, off)
+    new_cache["kv"] = _paged_write(cache["kv"], layer, new_rows, blk, off)
+    if kops.paged_attention_kernel(int8_pool=int8_pool, chunk=s,
+                                   interpret=interpret,
+                                   shape=cache["kv"].shape):
+        from repro.kernels.paged_attention import paged_attention
+        with scope("attn_core"):
+            out = paged_attention(q[:, 0], new_cache["kv"], layer, pt,
+                                  length[:, 0], interpret=interpret)
+        return out[:, None], new_cache
+    k, v = kvq.split_kv(_paged_gather(new_cache["kv"], layer, pt), heads)
+    with scope("attn_core"):
+        if not int8_pool:
+            out = dense_attention(q, k.astype(q.dtype), v.astype(q.dtype),
+                                  causal=False, kv_len=length)
+        else:
+            ks, vs = kvq.split_kv(
+                _paged_gather(new_cache["kv_scale"], layer, pt), heads)
+            if native_int8:
+                out = int8_dense_attention(q, k, ks, v, vs, kv_len=length)
+            else:
+                out = dense_attention(q, kvq.dequantize_kv(k, ks, q.dtype),
+                                      kvq.dequantize_kv(v, vs, q.dtype),
+                                      causal=False, kv_len=length)
+    return out, new_cache
+
+
+def _gqa_contiguous_decode(cache: Params, q, k_new, v_new, rows, start,
+                           length, *, native_int8: bool,
+                           ) -> Tuple[jax.Array, Params]:
+    """Write this step's k/v into a contiguous (B, Smax, KV, hd) cache at
+    per-row offsets ``rows`` (or the shared ``start``) and attend over it."""
+    if "k_scale" in cache:  # int8-quantized cache (§Perf C2)
         from repro.models import kvcache as kvq
         with scope("kv_write"):
-            kq, ks = kvq.quantize_kv(k_new)
-            vq, vs = kvq.quantize_kv(v_new)
-        new_cache = {
-            "k": _paged_write(cache["k"], kq, phys),
-            "v": _paged_write(cache["v"], vq, phys),
-            "k_scale": _paged_write(cache["k_scale"], ks, phys),
-            "v_scale": _paged_write(cache["v_scale"], vs, phys),
-            "page_table": pt,
-        }
+            new_cache = kvq.update_quantized_kv(
+                cache, k_new, v_new,
+                rows if rows is not None else start)
+        new_cache = {kk: shard(vv, "batch", "kv_seq", "kv_heads", None)
+                     for kk, vv in new_cache.items()}
         if native_int8:
-            k_view = (_paged_gather(new_cache["k"], pt),
-                      _paged_gather(new_cache["k_scale"], pt))
-            v_view = (_paged_gather(new_cache["v"], pt),
-                      _paged_gather(new_cache["v_scale"], pt))
-            return new_cache, k_view, v_view
-        with scope("kv_gather"):
-            k_view = kvq.dequantize_kv(
-                _paged_gather(new_cache["k"], pt),
-                _paged_gather(new_cache["k_scale"], pt), k_new.dtype)
-            v_view = kvq.dequantize_kv(
-                _paged_gather(new_cache["v"], pt),
-                _paged_gather(new_cache["v_scale"], pt), v_new.dtype)
+            k_cache = (new_cache["k"], new_cache["k_scale"])
+            v_cache = (new_cache["v"], new_cache["v_scale"])
+        else:
+            k_cache = kvq.dequantize_kv(new_cache["k"],
+                                        new_cache["k_scale"], q.dtype)
+            v_cache = kvq.dequantize_kv(new_cache["v"],
+                                        new_cache["v_scale"], q.dtype)
     else:
-        new_cache = {
-            "k": _paged_write(cache["k"], k_new, phys),
-            "v": _paged_write(cache["v"], v_new, phys),
-            "page_table": pt,
-        }
-        k_view = _paged_gather(new_cache["k"], pt).astype(k_new.dtype)
-        v_view = _paged_gather(new_cache["v"], pt).astype(v_new.dtype)
-    return new_cache, k_view, v_view
+        if rows is not None:  # slot-indexed: per-row write offsets
+            k_cache = _update_rows(cache["k"], k_new, rows)
+            v_cache = _update_rows(cache["v"], v_new, rows)
+        else:
+            with scope("kv_write"):
+                k_cache = jax.lax.dynamic_update_slice(
+                    cache["k"], k_new.astype(cache["k"].dtype),
+                    (0, start, 0, 0))
+                v_cache = jax.lax.dynamic_update_slice(
+                    cache["v"], v_new.astype(cache["v"].dtype),
+                    (0, start, 0, 0))
+        k_cache = shard(k_cache, "batch", "kv_seq", "kv_heads", None)
+        v_cache = shard(v_cache, "batch", "kv_seq", "kv_heads", None)
+        new_cache = {"k": k_cache, "v": v_cache}
+    with scope("attn_core"):
+        if isinstance(k_cache, tuple):  # native int8: pools + scales
+            out = int8_dense_attention(q, k_cache[0], k_cache[1],
+                                       v_cache[0], v_cache[1],
+                                       kv_len=length)
+        else:
+            out = dense_attention(q, k_cache, v_cache, causal=False,
+                                  kv_len=length)
+    return out, new_cache
 
 
 # --------------------------------------------------------------------------
@@ -427,51 +471,18 @@ def gqa_apply(
             # in-chunk causal mask of the speculative verify step.
             length = (base[:, None].astype(jnp.int32) + 1
                       + jnp.arange(s, dtype=jnp.int32)[None, :])
-            native_int8 = kops.as_policy(use_pallas).int8_decode == "native"
+            policy = kops.as_policy(use_pallas)
+            native_int8 = policy.int8_decode == "native"
             if "page_table" in cache:  # paged block pool (DESIGN.md §8)
                 if rows is None:
                     rows = jnp.broadcast_to(start, (b,))
-                new_cache, k_cache, v_cache = _gqa_paged_update(
-                    cache, k_new, v_new, rows, native_int8=native_int8)
-            elif "k_scale" in cache:  # int8-quantized cache (§Perf C2)
-                from repro.models import kvcache as kvq
-                with scope("kv_write"):
-                    new_cache = kvq.update_quantized_kv(
-                        cache, k_new, v_new,
-                        rows if rows is not None else start)
-                new_cache = {kk: shard(vv, "batch", "kv_seq", "kv_heads", None)
-                             for kk, vv in new_cache.items()}
-                if native_int8:
-                    k_cache = (new_cache["k"], new_cache["k_scale"])
-                    v_cache = (new_cache["v"], new_cache["v_scale"])
-                else:
-                    k_cache = kvq.dequantize_kv(new_cache["k"],
-                                                new_cache["k_scale"], x.dtype)
-                    v_cache = kvq.dequantize_kv(new_cache["v"],
-                                                new_cache["v_scale"], x.dtype)
+                out, new_cache = _gqa_paged_decode(
+                    cache, q, k_new, v_new, rows, length,
+                    native_int8=native_int8, interpret=policy.interpret)
             else:
-                if rows is not None:  # slot-indexed: per-row write offsets
-                    k_cache = _update_rows(cache["k"], k_new, rows)
-                    v_cache = _update_rows(cache["v"], v_new, rows)
-                else:
-                    with scope("kv_write"):
-                        k_cache = jax.lax.dynamic_update_slice(
-                            cache["k"], k_new.astype(cache["k"].dtype),
-                            (0, start, 0, 0))
-                        v_cache = jax.lax.dynamic_update_slice(
-                            cache["v"], v_new.astype(cache["v"].dtype),
-                            (0, start, 0, 0))
-                k_cache = shard(k_cache, "batch", "kv_seq", "kv_heads", None)
-                v_cache = shard(v_cache, "batch", "kv_seq", "kv_heads", None)
-                new_cache = {"k": k_cache, "v": v_cache}
-            with scope("attn_core"):
-                if isinstance(k_cache, tuple):  # native int8: pools + scales
-                    out = int8_dense_attention(q, k_cache[0], k_cache[1],
-                                               v_cache[0], v_cache[1],
-                                               kv_len=length)
-                else:
-                    out = dense_attention(q, k_cache, v_cache, causal=False,
-                                          kv_len=length)
+                out, new_cache = _gqa_contiguous_decode(
+                    cache, q, k_new, v_new, rows, start, length,
+                    native_int8=native_int8)
 
     with scope("attn_proj"):
         out = out.reshape(b, s, h * hd)

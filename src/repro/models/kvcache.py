@@ -19,7 +19,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["quantize_kv", "dequantize_kv", "init_quantized_kv", "update_quantized_kv"]
+__all__ = ["quantize_kv", "dequantize_kv", "init_quantized_kv", "update_quantized_kv",
+           "fuse_kv", "split_kv", "quantize_rows"]
 
 
 def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -72,3 +73,28 @@ def update_quantized_kv(cache: dict, k_new: jax.Array, v_new: jax.Array,
         "k_scale": jax.lax.dynamic_update_slice(cache["k_scale"], ks, at),
         "v_scale": jax.lax.dynamic_update_slice(cache["v_scale"], vs, at),
     }
+
+
+# --------------------------------------------------------------------------
+# Paged pool rows (serving/paged_cache.py)
+# --------------------------------------------------------------------------
+
+def fuse_kv(k: jax.Array, v: jax.Array) -> jax.Array:
+    """k, v: (..., KV, hd) -> pool rows (..., KV * 2 * hd): each KV head's
+    keys, then its values."""
+    return jnp.stack([k, v], axis=-2).reshape(k.shape[:-2] + (-1,))
+
+
+def split_kv(rows: jax.Array, heads: int) -> Tuple[jax.Array, jax.Array]:
+    """Pool rows (..., KV * 2 * w) -> k, v: (..., KV, w); ``w`` is hd for
+    values and 1 for the int8 pool's scales."""
+    x = rows.reshape(rows.shape[:-1] + (heads, 2, -1))
+    return x[..., 0, :], x[..., 1, :]
+
+
+def quantize_rows(rows: jax.Array, heads: int) -> Tuple[jax.Array, jax.Array]:
+    """Pool rows -> (int8 rows, bf16 scales (..., KV * 2)): one scale per
+    head's key vector and per head's value vector, as ``quantize_kv``."""
+    q, scale = quantize_kv(rows.reshape(rows.shape[:-1] + (heads, 2, -1)))
+    return (q.reshape(rows.shape),
+            scale.reshape(rows.shape[:-1] + (heads * 2,)))

@@ -155,6 +155,30 @@ def _scan_stack(stacked: Params, h: jax.Array, body, cache: Optional[Params],
     return h, new_cache, jnp.sum(auxs)
 
 
+def _scan_paged(stacked: Params, h: jax.Array, body, cache: Params):
+    """Decode over a paged pool (serving/paged_cache.py): scan the layers
+    with the stack's pool leaves in the carry.
+
+    Each layer gets the whole pool, its layer index and the page table,
+    writes its new rows in place and reads its own layer of the pool, so
+    no operation moves bytes in proportion to the pool: scanning the pool
+    as ``xs`` and restacking it as ``ys`` would copy all of it every step.
+    """
+    pools = {k: v for k, v in cache.items() if k != "page_table"}
+    table = cache["page_table"]
+    n_layers = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+
+    def scan_body(carry, xs):
+        hh, pool = carry
+        lp, layer = xs
+        hh, new, aux = body(lp, hh, dict(pool, layer=layer, page_table=table))
+        return (hh, {k: new[k] for k in pool}), aux
+
+    (h, pools), auxs = jax.lax.scan(
+        scan_body, (h, pools), (stacked, jnp.arange(n_layers, dtype=jnp.int32)))
+    return h, dict(pools, page_table=table), jnp.sum(auxs)
+
+
 # --------------------------------------------------------------------------
 # Model init
 # --------------------------------------------------------------------------
@@ -296,9 +320,13 @@ def lm_apply(
                 body = functools.partial(
                     _decoder_body, cfg=cfg, rope=rope, mode=mode, pos=pos,
                     moe_layer=moe_layer, use_pallas=use_pallas)
-                h, nc, aux = _scan_stack(p[name], h, body,
-                                         cache.get(name) if cache else None,
-                                         remat)
+                stack_cache = cache.get(name) if cache else None
+                if mode == "decode" and stack_cache is not None \
+                        and "page_table" in stack_cache:
+                    h, nc, aux = _scan_paged(p[name], h, body, stack_cache)
+                else:
+                    h, nc, aux = _scan_stack(p[name], h, body, stack_cache,
+                                             remat)
                 new_cache[name] = nc
                 aux_total += aux
         elif fam == "vlm":

@@ -11,19 +11,28 @@ relative to ``num_slots * max_len`` (DESIGN.md §8).
 
 Layout per layer stack (mirrors ``models.lm.init_cache`` stack names)::
 
-    {"k": (L, num_blocks, block_size, KV, hd),      # int8 when quantized
-     "v": (L, num_blocks, block_size, KV, hd),
-     ["k_scale"/"v_scale": (L, num_blocks, block_size, KV, 1) bf16,]
-     "page_table": (L, num_slots, max_blocks) int32}
+    {"kv": (L, num_blocks, block_size, KV * 2 * hd),   # int8 when quantized
+     ["kv_scale": (L, num_blocks, block_size, KV * 2) bf16,]
+     "page_table": (num_slots, max_blocks) int32}
 
-``page_table`` rides inside the cache tree (broadcast over L) so the
-layer-scan in ``models.lm`` needs no new plumbing: each scanned layer sees
-its pool slice plus the shared (num_slots, max_blocks) table, and
-``models.attention`` takes the paged decode path whenever the key is
+A block row holds one position of every KV head: head ``g``'s keys in the
+lanes ``[g * 2hd, g * 2hd + hd)`` and its values in the next ``hd``
+(``models.kvcache.fuse_kv``).  With ``2 * hd`` a multiple of 128 a row is a
+whole number of vregs, so the chip stores the pool row-major and unpadded
+and the paged-attention kernel (``kernels/paged_attention.py``) reads it as
+it lies.  A (..., KV, hd) layout would not do: the chip would store it
+transposed to avoid padding ``hd`` = 64 lanes, and every kernel call would
+first copy the pool into a layout the kernel can read.
+
+``page_table`` rides inside the cache tree, one per stack.  The decode
+forward (``models.lm``) carries the stack's pool leaves through its layer
+loop and hands each layer the whole pool, its layer index and the
+(num_slots, max_blocks) table; ``models.attention`` writes the step's rows
+into the pool in place and takes the paged decode path whenever the key is
 present.  **Block 0 is a reserved sink**: retired slots' page tables point
 at it, so the fixed-shape decode step can keep writing for inactive rows
-without corrupting live blocks; reads past a slot's length are masked by
-``kv_len`` exactly like contiguous-cache padding.
+without corrupting live blocks; reads past a slot's length are masked
+exactly like contiguous-cache padding.
 
 Host-side bookkeeping (:class:`BlockAllocator`, :class:`PageTableManager`)
 is plain numpy — the device only ever sees the pool leaves and the int32
@@ -248,14 +257,12 @@ def init_paged_cache(cfg: ModelConfig, num_slots: int, num_blocks: int,
     kv_dtype = jnp.int8 if quant else cfg.cdtype
 
     def pool(n: int) -> Dict[str, Any]:
-        d = {"k": jnp.zeros((n, num_blocks, block_size, kv, hd), kv_dtype),
-             "v": jnp.zeros((n, num_blocks, block_size, kv, hd), kv_dtype)}
+        d = {"kv": jnp.zeros((n, num_blocks, block_size, kv * 2 * hd),
+                             kv_dtype)}
         if quant:
-            d["k_scale"] = jnp.zeros((n, num_blocks, block_size, kv, 1),
-                                     jnp.bfloat16)
-            d["v_scale"] = jnp.zeros((n, num_blocks, block_size, kv, 1),
-                                     jnp.bfloat16)
-        d["page_table"] = jnp.zeros((n, num_slots, max_blocks), jnp.int32)
+            d["kv_scale"] = jnp.zeros((n, num_blocks, block_size, kv * 2),
+                                      jnp.bfloat16)
+        d["page_table"] = jnp.zeros((num_slots, max_blocks), jnp.int32)
         return d
 
     return {name: pool(n) for name, n in _stack_layers(cfg).items()}
@@ -265,21 +272,18 @@ def with_page_table(cache: Dict[str, Any], table: np.ndarray,
                     sharding=None) -> Dict[str, Any]:
     """Swap the (num_slots, max_blocks) page table into every stack.
 
-    Called when the table changed (admission / growth / retirement); the
-    broadcast over L is a view until the device copy (a few KiB).
-    ``sharding``: placement for the uploaded table — pass the sharding the
+    Called when the table changed (admission / growth / retirement): a
+    few KiB per stack, each stack its own buffer, since the cache is
+    donated.  ``sharding``: placement for the uploaded table — pass the sharding the
     compiled step echoes its table output with (NamedSharding(mesh, P())
     under the serve step's axis_rules), so steady-state steps that feed the
     echoed cache back hit the same executable signature."""
-    out = {}
-    for name, stack in cache.items():
-        n = stack["k"].shape[0]
-        new = dict(stack)
-        bcast = np.ascontiguousarray(np.broadcast_to(table, (n,) + table.shape))
-        new["page_table"] = (jax.device_put(bcast, sharding)
-                             if sharding is not None else jnp.asarray(bcast))
-        out[name] = new
-    return out
+    def put():
+        return (jax.device_put(table, sharding) if sharding is not None
+                else jnp.asarray(table))
+
+    return {name: dict(stack, page_table=put())
+            for name, stack in cache.items()}
 
 
 def paged_pool_bytes(cache: Dict[str, Any]) -> int:
@@ -296,37 +300,30 @@ def insert_prefill_paged(cache: Dict[str, Any], prefill_cache: Dict[str, Any],
     forward; ``page_row`` is the slot's (max_blocks,) page-table row.  All P
     padded positions are written — tail positions beyond the prompt map to
     the slot's own partially-filled last block or to the sink block, and are
-    either overwritten by decode or masked by ``kv_len``.  Quantizes on the
-    way in when the pool is int8.  Pure function of arrays: jit it once.
+    either overwritten by decode or masked by the live length.  Quantizes
+    on the way in when the pool is int8.  Pure function of arrays: jit it
+    once, with the cache donated so the pool is written in place.
     """
+    from repro.models import kvcache as kvq
     out = {}
     for name, stack in cache.items():
-        pool_k = stack["k"]
-        bs = pool_k.shape[2]
-        p_len = prefill_cache[name]["k"].shape[2]
-        j = jnp.arange(p_len, dtype=jnp.int32)
-        phys = page_row[j // bs].astype(jnp.int32) * bs + j % bs  # (P,)
-
-        def write(pool, vals):  # pool (L,NB,BS,...), vals (L,P,...)
-            nb = pool.shape[1]
-            flat = pool.reshape((pool.shape[0], nb * bs) + pool.shape[3:])
-            flat = flat.at[:, phys].set(vals.astype(pool.dtype))
-            return flat.reshape(pool.shape)
-
+        bs = stack["kv"].shape[2]
         k_new = prefill_cache[name]["k"][:, 0]  # (L, P, KV, hd)
-        v_new = prefill_cache[name]["v"][:, 0]
+        rows = kvq.fuse_kv(k_new, prefill_cache[name]["v"][:, 0])
+        # every (layer, position) row by its full index: a scatter whose
+        # window is one row keeps the pool's layout, so it updates in place
+        # (with the layer axis as a window dim XLA transposes the whole
+        # pool in and out)
+        j = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :]
+        layer = jnp.arange(rows.shape[0], dtype=jnp.int32)[:, None]
+        blk, off = page_row.astype(jnp.int32)[j // bs], j % bs
         new = dict(stack)
-        if "k_scale" in stack:
-            from repro.models import kvcache as kvq
-            kq, ks = kvq.quantize_kv(k_new)
-            vq, vs = kvq.quantize_kv(v_new)
-            new["k"] = write(stack["k"], kq)
-            new["v"] = write(stack["v"], vq)
-            new["k_scale"] = write(stack["k_scale"], ks)
-            new["v_scale"] = write(stack["v_scale"], vs)
-        else:
-            new["k"] = write(stack["k"], k_new)
-            new["v"] = write(stack["v"], v_new)
+        if "kv_scale" in stack:
+            rows, scales = kvq.quantize_rows(rows, k_new.shape[2])
+            new["kv_scale"] = stack["kv_scale"].at[layer, blk, off].set(
+                scales)
+        new["kv"] = stack["kv"].at[layer, blk, off].set(
+            rows.astype(stack["kv"].dtype))
         out[name] = new
     return out
 

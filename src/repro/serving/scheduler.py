@@ -205,29 +205,31 @@ class Scheduler:
             self.cache = pc.init_paged_cache(cfg, num_slots, num_blocks,
                                              block_size, max_blocks)
             # commit the pool to its lifetime placement up front: pool
-            # leaves KV-head-sharded over model (page tables replicated) —
-            # the same specs every step clamps its cache outputs to, so
-            # the executable signature never drifts between the first call
-            # (fresh pool) and steady state (echoed jit outputs).  On one
-            # device this is just an explicit commit; without it the
-            # uncommitted init pool and the committed first-insert output
-            # key two insert executables on multi-device platforms.
-            self.cache = jax.tree_util.tree_map(
-                lambda x, sp: jax.device_put(x, NamedSharding(mesh, sp)),
-                self.cache, paged_pool_specs(self.cache, mesh))
-
+            # leaves KV-head-sharded over model (page tables replicated).
+            # Every program that returns the cache pins its output to the
+            # same placement (``out_shardings``), so the executable
+            # signature never drifts between the first call (fresh pool,
+            # uploaded table) and steady state (echoed jit outputs): left
+            # to itself, jit may name an equivalent placement differently,
+            # on one device too.
+            pinned = jax.tree_util.tree_map(
+                lambda x, sp: NamedSharding(mesh, sp), self.cache,
+                paged_pool_specs(self.cache, mesh, cfg.num_kv_heads))
+            self.cache = jax.device_put(self.cache, pinned)
             # the cache operand is donated: the pool updates in place
             # instead of double-buffering (2x the KV memory the paged
-            # design exists to bound)
+            # design exists to bound).  A function of this scheduler's
+            # own, so its compile count is this engine's alone.
             def _insert_fn(cache, pcache, page_row):
-                return steps_mod.clamp_paged_cache(
-                    pc.insert_prefill_paged(cache, pcache, page_row), mesh)
+                return pc.insert_prefill_paged(cache, pcache, page_row)
 
-            self._insert = jax.jit(_insert_fn, donate_argnums=(0,))
+            self._insert = jax.jit(_insert_fn, donate_argnums=(0,),
+                                   out_shardings=pinned)
             self.prefix = (RadixCache(self.pages.allocator, block_size)
                            if prefix_cache else None)
         else:
             self.pages = None
+            pinned = None  # contiguous rows: placement left to jit
             self.cache = pc.init_slot_cache(cfg, num_slots, window)
             self._insert = jax.jit(pc.insert_prefill_rows,
                                    donate_argnums=(0,))
@@ -235,9 +237,11 @@ class Scheduler:
 
         self._prefill = jax.jit(steps_mod.build_slot_prefill_step(run, mesh))
         self._decode = jax.jit(steps_mod.build_serve_step(run, mesh),
-                               donate_argnums=(1,))
+                               donate_argnums=(1,),
+                               out_shardings=(None, pinned, None))
         self._extend = (jax.jit(steps_mod.build_extend_step(run, mesh),
-                                donate_argnums=(1,))
+                                donate_argnums=(1,),
+                                out_shardings=(pinned, None))
                         if self.prefix is not None else None)
         if self.spec_k:
             # two extra once-compiled programs: the k-step fused draft
@@ -245,9 +249,10 @@ class Scheduler:
             # (B, k+1) chunked verify
             self._draft = jax.jit(
                 steps_mod.build_draft_chain(run, mesh, self.spec_k),
-                donate_argnums=(1,))
+                donate_argnums=(1,), out_shardings=(pinned, None))
             self._verify = jax.jit(steps_mod.build_verify_step(run, mesh),
-                                   donate_argnums=(1,))
+                                   donate_argnums=(1,),
+                                   out_shardings=(pinned, None))
         else:
             self._draft = self._verify = None
 

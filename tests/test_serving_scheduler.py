@@ -148,7 +148,7 @@ def test_int8_paged_scheduler_serves():
     sched = Scheduler(run, params, mesh, num_slots=2, max_len=32,
                       prefill_len=16, block_size=4)
     assert sched.layout == "paged"
-    assert "k_scale" in sched.cache["stack"]  # quantized pool + scales
+    assert "kv_scale" in sched.cache["stack"]  # quantized pool + scales
     rids = [sched.submit(p, max_new=5)
             for p in _prompts(3, run.model.vocab_size, seed=11)]
     out = sched.run()
